@@ -12,8 +12,6 @@ Prints ``name,us_per_call,derived`` CSV rows:
   schedule_gen_scaling — §3: strongly-polynomial generation time vs size
   schedule_sweep       — compile+verify the full topology zoo in parallel,
                          emitting BENCH_schedules.json (see repro.cache.sweep)
-  jax_collectives      — wall-time of tree-pipeline vs XLA collectives on
-                         8 host devices (subprocess)
 
 Modes: default runs everything; ``--smoke`` runs only the 3-topology sweep
 smoke (<60s, CI); ``--sweep`` runs only the full sweep.
@@ -22,9 +20,7 @@ from __future__ import annotations
 
 import argparse
 import os
-import subprocess
 import sys
-import textwrap
 import time
 from fractions import Fraction
 
@@ -162,53 +158,6 @@ def schedule_sweep(out_path: str, smoke: bool = False,
         raise SystemExit(f"schedule sweep claim mismatches: {bad}")
 
 
-def jax_collectives() -> None:
-    """Wall time of the executable tree-pipeline collectives vs XLA's
-    built-ins on 8 host CPU devices (latency-bound toy, but end-to-end)."""
-    code = textwrap.dedent("""
-        import time
-        import jax, jax.numpy as jnp, numpy as np
-        try:
-            from jax import shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
-        from jax.sharding import Mesh, PartitionSpec as P
-        from repro.api import Collectives
-        from repro.comms import tree_all_reduce
-
-        mesh = Mesh(np.array(jax.devices()), ('x',))
-        coll = Collectives(num_chunks=4)
-        rs, ag = coll.program('bring:8', kind='allreduce')
-        x = jax.random.normal(jax.random.PRNGKey(0), (8, 1 << 16))
-
-        tree = jax.jit(shard_map(
-            lambda v: tree_all_reduce(v[0], rs, ag, 'x')[None],
-            mesh=mesh, in_specs=P('x'), out_specs=P('x')))
-        xla = jax.jit(shard_map(
-            lambda v: jax.lax.psum(v[0], 'x')[None],
-            mesh=mesh, in_specs=P('x'), out_specs=P('x')))
-        for name, fn in (('tree', tree), ('xla_psum', xla)):
-            fn(x).block_until_ready()
-            t0 = time.perf_counter()
-            for _ in range(20):
-                out = fn(x)
-            out.block_until_ready()
-            us = (time.perf_counter() - t0) / 20 * 1e6
-            print(f'jax_collectives.allreduce_{name},{us:.1f},'
-                  f'bytes={x.nbytes}')
-    """)
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
-                                       "src"))
-    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, env=env, timeout=900)
-    if out.returncode:
-        row("jax_collectives.FAILED", 0.0, out.stderr.strip()[-120:])
-    else:
-        print(out.stdout.strip(), flush=True)
-
-
 def build_parser() -> argparse.ArgumentParser:
     """The benchmark CLI (exposed separately so tools/check_docs.py can
     assert the documented flags match)."""
@@ -257,7 +206,6 @@ def main(argv: list[str] | None = None) -> None:
     schedule_gen_scaling()
     schedule_sweep(args.out, cache_dir=args.cache_dir,
                    pack_jobs=args.pack_jobs)
-    jax_collectives()
 
 
 if __name__ == "__main__":

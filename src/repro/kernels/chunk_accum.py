@@ -17,7 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from ._compat import CompilerParams as _CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _accum_kernel(acc_ref, upd_ref, out_ref):
@@ -45,7 +45,7 @@ def chunk_accum(acc: jax.Array, update: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((bn, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, c), acc.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(acc, update)

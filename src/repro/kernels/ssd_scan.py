@@ -26,25 +26,28 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from ._compat import CompilerParams as _CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _ssd_chunk_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref,
                       y_ref, state_ref, *, chunk: int):
     x = x_ref[0].astype(jnp.float32)          # [Q, P]
-    dt = dt_ref[0].astype(jnp.float32)        # [Q]
-    a = a_ref[0, 0]                           # scalar (this head's A)
+    dt = dt_ref[0].astype(jnp.float32)        # [Q, 1]
+    a = a_ref[pl.program_id(0)]               # scalar (this head's A)
     b = b_ref[0].astype(jnp.float32)          # [Q, N]
     c = c_ref[0].astype(jnp.float32)          # [Q, N]
 
-    da = dt * a                               # [Q]
-    cum = jnp.cumsum(da)                      # [Q]
-    diff = cum[:, None] - cum[None, :]        # [Q, Q]
     iq = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jq = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    ll = jnp.where(iq >= jq, jnp.exp(diff), 0.0)
+    causal = iq >= jq
+    da = dt * a                               # [Q, 1]
+    # inclusive prefix sum as a masked row reduction (Mosaic has no cumsum)
+    cum = jnp.sum(jnp.where(causal, da.T, 0.0), axis=1,
+                  keepdims=True)              # [Q, 1]
+    diff = cum - cum.T                        # [Q, Q]
+    ll = jnp.where(causal, jnp.exp(diff), 0.0)
 
-    xdt = x * dt[:, None]                     # [Q, P]
+    xdt = x * dt                              # [Q, P]
     scores = jax.lax.dot_general(             # C·B^T  [Q, Q]
         c, b, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -53,9 +56,9 @@ def _ssd_chunk_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref,
         preferred_element_type=jnp.float32)
     y_ref[0] = y.astype(y_ref.dtype)
 
-    decay_state = jnp.exp(cum[-1] - cum)      # [Q]
+    decay_state = jnp.exp(cum[-1:] - cum)     # [Q, 1]
     state = jax.lax.dot_general(              # xdt^T @ (decay*B)  [P, N]
-        xdt, b * decay_state[:, None], (((0,), (0,)), ((), ())),
+        xdt, b * decay_state, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     state_ref[0, 0] = state.astype(state_ref.dtype)
 
@@ -82,8 +85,8 @@ def ssd_chunk_intra(x: jax.Array, dt: jax.Array, a: jax.Array,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, chunk, p), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, chunk), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, chunk, 1), lambda i, j: (i, j, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, chunk, n), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, chunk, n), lambda i, j: (i, j, 0)),
         ],
@@ -95,7 +98,7 @@ def ssd_chunk_intra(x: jax.Array, dt: jax.Array, a: jax.Array,
             jax.ShapeDtypeStruct((bh, s, p), x.dtype),
             jax.ShapeDtypeStruct((bh, l, p, n), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(x, dt, a.reshape(bh, 1), b, c)
+    )(x, dt.reshape(bh, s, 1), a.astype(jnp.float32), b, c)

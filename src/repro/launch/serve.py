@@ -13,12 +13,11 @@ recompiling it.
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 import time
 
 
-def main() -> int:
+def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="qwen3-8b")
     ap.add_argument("--reduced", action="store_true")
@@ -27,7 +26,9 @@ def main() -> int:
     ap.add_argument("--batch-size", type=int, default=2)
     ap.add_argument("--max-len", type=int, default=256)
     ap.add_argument("--model-parallel", type=int, default=1)
-    ap.add_argument("--host-devices", type=int, default=0)
+    ap.add_argument("--host-devices", type=int, default=0,
+                    help="CPU tests only: re-exec on N forced CPU host "
+                         "devices (sets JAX_PLATFORMS=cpu)")
     ap.add_argument("--schedule-cache", default="",
                     help="pre-compile the model-axis tree-pipeline collective "
                          "programs into this on-disk artifact cache")
@@ -41,13 +42,13 @@ def main() -> int:
                          "the program in place (CollectiveContext.hot_swap) "
                          "and distributes parameters over the degraded "
                          "fabric")
-    args = ap.parse_args()
+    argv = sys.argv[1:] if argv is None else list(argv)
+    args = ap.parse_args(argv)
 
-    if args.host_devices and "XLA_FLAGS" not in os.environ:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.host_devices}")
-        os.execv(sys.executable, [sys.executable, "-m", "repro.launch.serve"]
-                 + [a for a in sys.argv[1:]])
+    from .runtime import force_host_devices, use_compile_cache
+    if args.host_devices:
+        force_host_devices(args.host_devices, "repro.launch.serve", argv)
+    use_compile_cache()
 
     import jax
     import jax.numpy as jnp
@@ -89,10 +90,6 @@ def main() -> int:
         # single-root broadcast artifact: every device ends up with the
         # root's bytes (MPI_Bcast semantics) before TP sharding applies.
         from repro.comms import tree_broadcast
-        try:
-            from jax import shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         prog = ctx.broadcast_program("model", root=0)
@@ -117,11 +114,8 @@ def main() -> int:
             return jax.tree.map(
                 lambda x: tree_broadcast(x, prog, "model"), tree)
 
-        kwargs = dict(mesh=mesh, in_specs=P(), out_specs=P())
-        try:
-            bcast = shard_map(_bcast_tree, check_rep=False, **kwargs)
-        except TypeError:       # newer jax: check_rep retired
-            bcast = shard_map(_bcast_tree, **kwargs)
+        bcast = jax.shard_map(_bcast_tree, mesh=mesh, in_specs=P(),
+                              out_specs=P(), check_vma=False)
         t0 = time.perf_counter()
         with mesh:
             params = jax.jit(bcast)(params)

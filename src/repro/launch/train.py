@@ -1,12 +1,12 @@
 """Production training driver: mesh + sharding policy + sharded data +
 fault-tolerant supervisor, end to end.
 
-On a real TPU slice this runs under `jax.distributed.initialize()` with the
-production 16x16 / 2x16x16 meshes; on this container it runs the same code
-path over host devices (--host-devices N re-execs with a forced device
-count).  The paper's collective layer plugs in at two points: the per-axis
-topology models used by GSPMD cost analysis, and (collectives=pipeline) the
-BucketedAllReduce gradient hook built from tree-pipeline schedules.
+On a TPU host it runs on the chips JAX finds (`chip_smoke.py` drives it
+in-process on one chip, and on four with ``--chips 4``).  For tests on the
+CPU, --host-devices N re-execs on N forced CPU host devices.  The paper's
+collective layer plugs in at two points: the per-axis topology models used
+by GSPMD cost analysis, and (collectives=pipeline) the BucketedAllReduce
+gradient hook built from tree-pipeline schedules.
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-8b --reduced \
         --steps 50 --host-devices 8 --data-parallel 8
@@ -14,15 +14,19 @@ BucketedAllReduce gradient hook built from tree-pipeline schedules.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import sys
 
 
-def main() -> int:
+def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="qwen3-8b")
     ap.add_argument("--reduced", action="store_true",
                     help="reduced config (CPU-sized)")
+    ap.add_argument("--num-layers", type=int, default=0,
+                    help="cut the config's depth to N layers (0 keeps it); "
+                         "widths stay the published ones")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--global-batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
@@ -32,7 +36,8 @@ def main() -> int:
     ap.add_argument("--ckpt-dir", default="/tmp/repro_launch_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=25)
     ap.add_argument("--host-devices", type=int, default=0,
-                    help="re-exec with N forced host devices (CPU testing)")
+                    help="CPU tests only: re-exec on N forced CPU host "
+                         "devices (sets JAX_PLATFORMS=cpu)")
     ap.add_argument("--collectives", default="xla",
                     choices=("xla", "pipeline"),
                     help="xla: stock GSPMD all-reduces.  pipeline: gradients "
@@ -51,13 +56,13 @@ def main() -> int:
                          "repairs the affected per-axis schedules in place "
                          "(CollectiveContext.hot_swap) and retries the same "
                          "step without restoring a checkpoint")
-    args = ap.parse_args()
+    argv = sys.argv[1:] if argv is None else list(argv)
+    args = ap.parse_args(argv)
 
-    if args.host_devices and "XLA_FLAGS" not in os.environ:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.host_devices}")
-        os.execv(sys.executable, [sys.executable, "-m", "repro.launch.train"]
-                 + [a for a in sys.argv[1:]])
+    from .runtime import force_host_devices, use_compile_cache
+    if args.host_devices:
+        force_host_devices(args.host_devices, "repro.launch.train", argv)
+    use_compile_cache()
 
     import jax
     import jax.numpy as jnp
@@ -101,6 +106,8 @@ def main() -> int:
             print(ctx.compile_stats_report())
 
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
+    if args.num_layers:
+        cfg = dataclasses.replace(cfg, num_layers=args.num_layers)
     model = build_model(cfg, remat=True)
 
     params = model.init(jax.random.PRNGKey(0), jnp.float32)
@@ -139,10 +146,6 @@ def main() -> int:
             # lowered to ppermute programs and wrapped as the
             # BucketedAllReduce hook of make_train_step, executed inside
             # shard_map.
-            try:
-                from jax import shard_map
-            except ImportError:
-                from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
             red = ctx.bucketed_allreduce("data", wire_dtype=None)
@@ -161,12 +164,10 @@ def main() -> int:
                 m = {k: jax.lax.pmean(v, "data") for k, v in m.items()}
                 return p, o, m
 
-            kwargs = dict(mesh=mesh, in_specs=(P(), P(), P("data")),
-                          out_specs=(P(), P(), P()))
-            try:
-                step_sm = shard_map(spmd_step, check_rep=False, **kwargs)
-            except TypeError:       # newer jax: check_rep retired
-                step_sm = shard_map(spmd_step, **kwargs)
+            step_sm = jax.shard_map(spmd_step, mesh=mesh,
+                                    in_specs=(P(), P(), P("data")),
+                                    out_specs=(P(), P(), P()),
+                                    check_vma=False)
             with mesh:
                 return jax.jit(step_sm, donate_argnums=(0, 1))
         with mesh:
@@ -211,7 +212,7 @@ def main() -> int:
                           ckpt_every=args.ckpt_every,
                           on_link_fault=on_link_fault)
     state, final = sup.run(state=(params, opt), num_steps=args.steps,
-                           step_fn=step_fn, log_every=10)
+                           step_fn=step_fn, log_every=1)
     print(f"done at step {final}; stragglers: {len(sup.monitor.flagged)}; "
           f"link faults repaired: "
           f"{injector.fired if injector else False}")

@@ -32,6 +32,8 @@ import math
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+import jax
+
 from . import checkpoint as ckpt
 
 
@@ -166,6 +168,8 @@ class TrainSupervisor:
             try:
                 t0 = time.perf_counter()
                 state, metrics = step_fn(step, state)
+                # dispatch is asynchronous: time the step, not its enqueue
+                jax.block_until_ready((state, metrics))
                 dt = time.perf_counter() - t0
                 if self.monitor.observe(step, dt):
                     log(f"[ft] straggler at step {step}: {dt:.3f}s "

@@ -26,10 +26,7 @@ def run_snippet(code: str) -> str:
 def test_tree_collectives_match_references():
     print(run_snippet("""
         import jax, jax.numpy as jnp, numpy as np
-        try:
-            from jax import shard_map
-        except ImportError:  # older jax: experimental namespace
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.topo import bidir_ring, fig1a, ring
         from repro.core.schedule import compile_allgather, compile_reduce_scatter
@@ -64,10 +61,7 @@ def test_tree_collectives_match_references():
 def test_tree_broadcast_and_reduce_match_references():
     print(run_snippet("""
         import jax, jax.numpy as jnp, numpy as np
-        try:
-            from jax import shard_map
-        except ImportError:  # older jax: experimental namespace
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.topo import bidir_ring, fig1a
         from repro.core.schedule import compile_broadcast, compile_reduce
@@ -101,10 +95,7 @@ def test_tree_broadcast_and_reduce_match_references():
 def test_tree_all_to_all_matches_reference():
     print(run_snippet("""
         import jax, jax.numpy as jnp, numpy as np
-        try:
-            from jax import shard_map
-        except ImportError:  # older jax: experimental namespace
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.api import Collectives
         from repro.topo import bidir_ring, fig1a
@@ -135,10 +126,7 @@ def test_moe_forward_alltoall_transport_parity():
     dense-dispatch moe_forward."""
     print(run_snippet("""
         import jax, jax.numpy as jnp, numpy as np
-        try:
-            from jax import shard_map
-        except ImportError:  # older jax: experimental namespace
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.api import Collectives
         from repro.topo import bidir_ring
@@ -186,10 +174,7 @@ def test_bucketed_allreduce_from_cached_artifact():
     print(run_snippet("""
         import tempfile
         import jax, jax.numpy as jnp, numpy as np
-        try:
-            from jax import shard_map
-        except ImportError:  # older jax: experimental namespace
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.api import Collectives
         from repro.cache import ScheduleCache
@@ -218,10 +203,7 @@ def test_bucketed_allreduce_from_cached_artifact():
 def test_multi_axis_hierarchical_allreduce():
     print(run_snippet("""
         import jax, jax.numpy as jnp, numpy as np
-        try:
-            from jax import shard_map
-        except ImportError:  # older jax: experimental namespace
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.comms.mesh_axes import CollectiveContext
         from repro.comms.collectives import tree_all_reduce_multi
@@ -243,10 +225,7 @@ def test_multi_axis_hierarchical_allreduce():
 def test_bf16_reduce_scatter_f32_accumulation():
     print(run_snippet("""
         import jax, jax.numpy as jnp, numpy as np
-        try:
-            from jax import shard_map
-        except ImportError:  # older jax: experimental namespace
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.topo import bidir_ring
         from repro.core.schedule import compile_reduce_scatter
@@ -272,10 +251,7 @@ def test_bf16_reduce_scatter_f32_accumulation():
 def test_bucketed_overlap_allreduce():
     print(run_snippet("""
         import jax, jax.numpy as jnp, numpy as np
-        try:
-            from jax import shard_map
-        except ImportError:  # older jax: experimental namespace
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.topo import bidir_ring
         from repro.core.schedule import compile_allgather, \\
