@@ -47,12 +47,19 @@ _pending: List[threading.Thread] = []
 
 
 def save_async(ckpt_dir: str, step: int, tree: Params) -> threading.Thread:
-    """Fetch to host synchronously, serialise on a background thread."""
+    """Fetch to host synchronously, serialise on a background thread.
+    Writes land in call order, so LATEST never moves back to an older step
+    when an earlier write is still running."""
     leaves = _flatten_with_paths(tree)
     host = {k: np.asarray(v) for k, v in leaves}   # device->host blocks here
+    prev = _pending[-1] if _pending else None
 
-    t = threading.Thread(target=_write, args=(ckpt_dir, step, tree, host),
-                         daemon=True)
+    def write():
+        if prev is not None:
+            prev.join()
+        _write(ckpt_dir, step, tree, host)
+
+    t = threading.Thread(target=write, daemon=True)
     t.start()
     _pending.append(t)
     return t

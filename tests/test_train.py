@@ -2,6 +2,7 @@
 checkpointing, fault-tolerant supervision, elastic planning."""
 import os
 import tempfile
+import time
 
 import jax
 import jax.numpy as jnp
@@ -90,6 +91,26 @@ def test_checkpoint_roundtrip_and_gc(setup):
         assert step == 4
         for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(p2)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_save_async_writes_in_call_order(monkeypatch):
+    """A slow earlier async save must not land after a later one: LATEST
+    has to end on the newest step (the supervisor restores from it)."""
+    write = checkpoint._write
+
+    def slow_first(ckpt_dir, step, tree, host):
+        if step == 3:
+            time.sleep(0.3)
+        return write(ckpt_dir, step, tree, host)
+
+    monkeypatch.setattr(checkpoint, "_write", slow_first)
+    with tempfile.TemporaryDirectory() as d:
+        checkpoint.save_async(d, 3, {"n": jnp.zeros(())})
+        checkpoint.save_async(d, 6, {"n": jnp.ones(())})
+        checkpoint.wait_pending()
+        assert checkpoint.latest_step(d) == 6
+        state, step = checkpoint.restore(d, {"n": jnp.zeros(())})
+        assert (step, float(state["n"])) == (6, 1.0)
 
 
 def test_supervisor_recovers_from_crash(setup):
