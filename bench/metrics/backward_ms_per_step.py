@@ -1,0 +1,11 @@
+"""backward_ms_per_step: device time per traced step of the backward pass
+(scope path holds `transpose(`, recomputation left out), on the device
+with the most (bench/scopes.py)."""
+from bench import scopes
+
+
+def read(r):
+    got = scopes.of_run(r.summary)
+    if got is None or got.split is None:
+        return None
+    return got.split.per_step_ms("backward")

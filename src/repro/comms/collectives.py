@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from .executor import PermuteCall, PermuteProgram
 
 
@@ -34,13 +35,17 @@ def _run_call(buf: jax.Array, call: PermuteCall, axis_name: str,
               me: jax.Array, mode: str) -> jax.Array:
     send_idx = jnp.asarray(call.send_slots)[me]      # [width]
     recv_idx = jnp.asarray(call.recv_slots)[me]      # [width]
-    payload = jnp.take(buf, send_idx, axis=0)        # [width, chunk]
-    got = jax.lax.ppermute(payload, axis_name, list(call.perm))
-    if mode == "set":
-        # non-receivers target the trash row; receivers get exactly one write
-        return buf.at[recv_idx].set(got, mode="promise_in_bounds")
-    # reduce-scatter: accumulate the incoming partial into our partial
-    return buf.at[recv_idx].add(got, mode="promise_in_bounds")
+    with jax.named_scope(obs.COMMS_GATHER):
+        payload = jnp.take(buf, send_idx, axis=0)    # [width, chunk]
+    with jax.named_scope(obs.COMMS_PERMUTE):
+        got = jax.lax.ppermute(payload, axis_name, list(call.perm))
+    with jax.named_scope(obs.COMMS_SCATTER):
+        if mode == "set":
+            # non-receivers target the trash row; receivers get exactly
+            # one write
+            return buf.at[recv_idx].set(got, mode="promise_in_bounds")
+        # reduce-scatter: accumulate the incoming partial into our partial
+        return buf.at[recv_idx].add(got, mode="promise_in_bounds")
 
 
 def _run_program(buf: jax.Array, prog: PermuteProgram, axis_name: str,
@@ -71,17 +76,19 @@ def tree_all_gather(x: jax.Array, prog: PermuteProgram, axis_name: str,
     a, s = prog.axis_size, prog.slots_per_shard
     shard_elems = int(np.prod(x.shape)) if x.ndim else 1
     ce = _chunk_elems(shard_elems, s)
-    flat = jnp.ravel(x)
-    flat = jnp.pad(flat, (0, s * ce - shard_elems))
-    me = _me(axis_name)
-    buf = jnp.zeros((a * s + 1, ce), dtype=x.dtype)
-    buf = jax.lax.dynamic_update_slice_in_dim(
-        buf, flat.reshape(s, ce), me * s, axis=0)
+    with jax.named_scope(obs.COMMS_STAGE):
+        flat = jnp.ravel(x)
+        flat = jnp.pad(flat, (0, s * ce - shard_elems))
+        me = _me(axis_name)
+        buf = jnp.zeros((a * s + 1, ce), dtype=x.dtype)
+        buf = jax.lax.dynamic_update_slice_in_dim(
+            buf, flat.reshape(s, ce), me * s, axis=0)
     buf = _run_program(buf, prog, axis_name, mode="set")
-    out = buf[:a * s].reshape(a, s * ce)[:, :shard_elems]
-    out = out.reshape((a,) + x.shape)
-    if tiled:
-        out = out.reshape((a * x.shape[0],) + x.shape[1:]) if x.ndim else out
+    with jax.named_scope(obs.COMMS_STAGE):
+        out = buf[:a * s].reshape(a, s * ce)[:, :shard_elems]
+        out = out.reshape((a,) + x.shape)
+        if tiled and x.ndim:
+            out = out.reshape((a * x.shape[0],) + x.shape[1:])
     return out
 
 
@@ -107,16 +114,18 @@ def tree_reduce_scatter(x: jax.Array, prog: PermuteProgram, axis_name: str,
     ce = _chunk_elems(shard_elems, s)
     compute_dtype = accum_dtype or (
         jnp.float32 if x.dtype in (jnp.bfloat16, jnp.float16) else x.dtype)
-    flat = x.reshape(a, shard_elems).astype(compute_dtype)
-    flat = jnp.pad(flat, ((0, 0), (0, s * ce - shard_elems)))
-    buf = jnp.concatenate(
-        [flat.reshape(a * s, ce),
-         jnp.zeros((1, ce), dtype=compute_dtype)], axis=0)
+    with jax.named_scope(obs.COMMS_STAGE):
+        flat = x.reshape(a, shard_elems).astype(compute_dtype)
+        flat = jnp.pad(flat, ((0, 0), (0, s * ce - shard_elems)))
+        buf = jnp.concatenate(
+            [flat.reshape(a * s, ce),
+             jnp.zeros((1, ce), dtype=compute_dtype)], axis=0)
     buf = _run_program(buf, prog, axis_name, mode="add")
     me = _me(axis_name)
-    mine = jax.lax.dynamic_slice_in_dim(buf, me * s, s, axis=0)
-    out = mine.reshape(s * ce)[:shard_elems].reshape(shard_shape)
-    return out.astype(x.dtype)
+    with jax.named_scope(obs.COMMS_STAGE):
+        mine = jax.lax.dynamic_slice_in_dim(buf, me * s, s, axis=0)
+        out = mine.reshape(s * ce)[:shard_elems].reshape(shard_shape)
+        return out.astype(x.dtype)
 
 
 # ---------------------------------------------------------------------- #
@@ -149,17 +158,20 @@ def tree_all_to_all(x: jax.Array, prog: PermuteProgram, axis_name: str
     block_elems = int(np.prod(block_shape)) if len(block_shape) else 1
     ce = _chunk_elems(block_elems, kp)
     me = _me(axis_name)
-    flat = x.reshape(a, block_elems)
-    flat = jnp.pad(flat, ((0, 0), (0, kp * ce - block_elems)))
-    buf = jnp.zeros((a * s + 1, ce), dtype=x.dtype)
-    buf = jax.lax.dynamic_update_slice_in_dim(
-        buf, flat.reshape(s, ce), me * s, axis=0)
+    with jax.named_scope(obs.COMMS_STAGE):
+        flat = x.reshape(a, block_elems)
+        flat = jnp.pad(flat, ((0, 0), (0, kp * ce - block_elems)))
+        buf = jnp.zeros((a * s + 1, ce), dtype=x.dtype)
+        buf = jax.lax.dynamic_update_slice_in_dim(
+            buf, flat.reshape(s, ce), me * s, axis=0)
     buf = _run_program(buf, prog, axis_name, mode="set")
-    # source r's block for us sits at rows r*S + me*kp + t
-    rows = (jnp.arange(a) * s)[:, None] + me * kp + jnp.arange(kp)[None, :]
-    out = jnp.take(buf, rows.reshape(-1), axis=0)
-    out = out.reshape(a, kp * ce)[:, :block_elems]
-    return out.reshape((a,) + block_shape)
+    with jax.named_scope(obs.COMMS_STAGE):
+        # source r's block for us sits at rows r*S + me*kp + t
+        rows = ((jnp.arange(a) * s)[:, None] + me * kp
+                + jnp.arange(kp)[None, :])
+        out = jnp.take(buf, rows.reshape(-1), axis=0)
+        out = out.reshape(a, kp * ce)[:, :block_elems]
+        return out.reshape((a,) + block_shape)
 
 
 # ---------------------------------------------------------------------- #
@@ -180,17 +192,19 @@ def tree_broadcast(x: jax.Array, prog: PermuteProgram, axis_name: str
     root = prog.root
     shard_elems = int(np.prod(x.shape)) if x.ndim else 1
     ce = _chunk_elems(shard_elems, s)
-    flat = jnp.ravel(x)
-    flat = jnp.pad(flat, (0, s * ce - shard_elems))
-    buf = jnp.zeros((a * s + 1, ce), dtype=x.dtype)
-    # slot layout matches the executor: the root's chunks live at
-    # [root*s, (root+1)*s); every device stages its own copy there (only the
-    # root's is ever forwarded)
-    buf = jax.lax.dynamic_update_slice_in_dim(
-        buf, flat.reshape(s, ce), root * s, axis=0)
+    with jax.named_scope(obs.COMMS_STAGE):
+        flat = jnp.ravel(x)
+        flat = jnp.pad(flat, (0, s * ce - shard_elems))
+        buf = jnp.zeros((a * s + 1, ce), dtype=x.dtype)
+        # slot layout matches the executor: the root's chunks live at
+        # [root*s, (root+1)*s); every device stages its own copy there
+        # (only the root's is ever forwarded)
+        buf = jax.lax.dynamic_update_slice_in_dim(
+            buf, flat.reshape(s, ce), root * s, axis=0)
     buf = _run_program(buf, prog, axis_name, mode="set")
-    out = jax.lax.dynamic_slice_in_dim(buf, root * s, s, axis=0)
-    return out.reshape(s * ce)[:shard_elems].reshape(x.shape)
+    with jax.named_scope(obs.COMMS_STAGE):
+        out = jax.lax.dynamic_slice_in_dim(buf, root * s, s, axis=0)
+        return out.reshape(s * ce)[:shard_elems].reshape(x.shape)
 
 
 def tree_reduce(x: jax.Array, prog: PermuteProgram, axis_name: str,
@@ -209,14 +223,17 @@ def tree_reduce(x: jax.Array, prog: PermuteProgram, axis_name: str,
     ce = _chunk_elems(shard_elems, s)
     compute_dtype = accum_dtype or (
         jnp.float32 if x.dtype in (jnp.bfloat16, jnp.float16) else x.dtype)
-    flat = jnp.ravel(x).astype(compute_dtype)
-    flat = jnp.pad(flat, (0, s * ce - shard_elems))
-    buf = jnp.zeros((a * s + 1, ce), dtype=compute_dtype)
-    buf = jax.lax.dynamic_update_slice_in_dim(
-        buf, flat.reshape(s, ce), root * s, axis=0)
+    with jax.named_scope(obs.COMMS_STAGE):
+        flat = jnp.ravel(x).astype(compute_dtype)
+        flat = jnp.pad(flat, (0, s * ce - shard_elems))
+        buf = jnp.zeros((a * s + 1, ce), dtype=compute_dtype)
+        buf = jax.lax.dynamic_update_slice_in_dim(
+            buf, flat.reshape(s, ce), root * s, axis=0)
     buf = _run_program(buf, prog, axis_name, mode="add")
-    out = jax.lax.dynamic_slice_in_dim(buf, root * s, s, axis=0)
-    return out.reshape(s * ce)[:shard_elems].reshape(x.shape).astype(x.dtype)
+    with jax.named_scope(obs.COMMS_STAGE):
+        out = jax.lax.dynamic_slice_in_dim(buf, root * s, s, axis=0)
+        return out.reshape(s * ce)[:shard_elems].reshape(
+            x.shape).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------- #
@@ -232,15 +249,16 @@ def tree_all_reduce(x: jax.Array, rs_prog: PermuteProgram,
     orig_shape = x.shape
     elems = int(np.prod(orig_shape)) if x.ndim else 1
     pad = (-elems) % a
-    flat = jnp.ravel(x)
-    if pad:
-        flat = jnp.pad(flat, (0, pad))
-    flat = flat.reshape(a, (elems + pad) // a)
+    with jax.named_scope(obs.COMMS_STAGE):
+        flat = jnp.ravel(x)
+        if pad:
+            flat = jnp.pad(flat, (0, pad))
+        flat = flat.reshape(a, (elems + pad) // a)
     shard = tree_reduce_scatter(flat, rs_prog, axis_name,
                                 accum_dtype=accum_dtype)
     full = tree_all_gather(shard, ag_prog, axis_name)
-    out = full.reshape(-1)[:elems]
-    return out.reshape(orig_shape)
+    with jax.named_scope(obs.COMMS_STAGE):
+        return full.reshape(-1)[:elems].reshape(orig_shape)
 
 
 # ---------------------------------------------------------------------- #
@@ -262,13 +280,14 @@ def tree_all_reduce_multi(x: jax.Array, progs: Sequence[tuple],
     orig_shape = x.shape
     elems = int(np.prod(orig_shape)) if x.ndim else 1
     pad = (-elems) % a
-    flat = jnp.ravel(x)
-    if pad:
-        flat = jnp.pad(flat, (0, pad))
-    flat = flat.reshape(a, (elems + pad) // a)
+    with jax.named_scope(obs.COMMS_STAGE):
+        flat = jnp.ravel(x)
+        if pad:
+            flat = jnp.pad(flat, (0, pad))
+        flat = flat.reshape(a, (elems + pad) // a)
     shard = tree_reduce_scatter(flat, rs_p, axis,
                                 accum_dtype=accum_dtype)
     shard = tree_all_reduce_multi(shard, rest, accum_dtype=accum_dtype)
     full = tree_all_gather(shard, ag_p, axis)
-    out = full.reshape(-1)[:elems]
-    return out.reshape(orig_shape)
+    with jax.named_scope(obs.COMMS_STAGE):
+        return full.reshape(-1)[:elems].reshape(orig_shape)

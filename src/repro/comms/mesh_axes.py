@@ -17,6 +17,9 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Optional, Sequence, Tuple
 
+from jax.profiler import TraceAnnotation
+
+from repro import obs
 from repro.api import Collectives
 from repro.core.graph import DiGraph
 from repro.core.schedule import PipelineSchedule
@@ -95,9 +98,10 @@ class CollectiveContext:
         instead of being recomputed per kind."""
         if axis not in self._cache:
             topo = self.topology(axis)
-            ag, rs = self.collectives.pair(topo)
-            ag_prog, rs_prog = (self.collectives.lower(ag),
-                                self.collectives.lower(rs))
+            with TraceAnnotation(obs.SCHEDULE):
+                ag, rs = self.collectives.pair(topo)
+                ag_prog, rs_prog = (self.collectives.lower(ag),
+                                    self.collectives.lower(rs))
             self._cache[axis] = AxisSchedules(
                 axis_name=axis, topology=topo,
                 ag_sched=ag, rs_sched=rs,
@@ -109,8 +113,9 @@ class CollectiveContext:
         compiled into) the schedule cache as a single `repro.allreduce`
         artifact — the entry `BucketedAllReduce` consumers replay."""
         if axis not in self._allreduce:
-            self._allreduce[axis] = self.collectives.schedule(
-                self.topology(axis), kind="allreduce")
+            with TraceAnnotation(obs.SCHEDULE):
+                self._allreduce[axis] = self.collectives.schedule(
+                    self.topology(axis), kind="allreduce")
         return self._allreduce[axis]
 
     def bucketed_allreduce(self, axis: str, bucket_bytes: int = 64 << 20,
@@ -131,10 +136,11 @@ class CollectiveContext:
         memoized per (axis, root)."""
         key = (axis, root)
         if key not in self._broadcast:
-            sched = self.collectives.schedule(
-                self.topology(axis), kind="broadcast", root=root)
-            self._broadcast_scheds[key] = sched
-            self._broadcast[key] = self.collectives.lower(sched)
+            with TraceAnnotation(obs.SCHEDULE):
+                sched = self.collectives.schedule(
+                    self.topology(axis), kind="broadcast", root=root)
+                self._broadcast_scheds[key] = sched
+                self._broadcast[key] = self.collectives.lower(sched)
         return self._broadcast[key]
 
     def alltoall_program(self, axis: str) -> PermuteProgram:
@@ -144,10 +150,11 @@ class CollectiveContext:
         A−1 destination blocks back-to-back, so sub-chunking only multiplies
         ppermute calls without shortening the pipeline."""
         if axis not in self._alltoall:
-            sched = self.collectives.schedule(
-                self.topology(axis), kind="alltoall", num_chunks=1)
-            self._alltoall_scheds[axis] = sched
-            self._alltoall[axis] = self.collectives.lower(sched)
+            with TraceAnnotation(obs.SCHEDULE):
+                sched = self.collectives.schedule(
+                    self.topology(axis), kind="alltoall", num_chunks=1)
+                self._alltoall_scheds[axis] = sched
+                self._alltoall[axis] = self.collectives.lower(sched)
         return self._alltoall[axis]
 
     def allreduce_programs(self, axes: Sequence[str]

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from .collectives import tree_all_reduce
 from .executor import PermuteProgram
 
@@ -69,20 +70,22 @@ class BucketedAllReduce:
         buckets = partition_buckets(grads, self.bucket_bytes)
         out = list(leaves)
         for bucket in buckets:
-            flat = jnp.concatenate(
-                [jnp.ravel(leaves[i]) for i in bucket]) if len(bucket) > 1 \
-                else jnp.ravel(leaves[bucket[0]])
-            if self.wire_dtype is not None:
-                flat = flat.astype(self.wire_dtype)
+            with jax.named_scope(obs.COMMS_BUCKET):
+                flat = jnp.concatenate(
+                    [jnp.ravel(leaves[i]) for i in bucket]) \
+                    if len(bucket) > 1 else jnp.ravel(leaves[bucket[0]])
+                if self.wire_dtype is not None:
+                    flat = flat.astype(self.wire_dtype)
             red = tree_all_reduce(flat, self.rs_prog, self.ag_prog,
                                   self.axis_name,
                                   accum_dtype=jnp.float32)
-            off = 0
-            for i in bucket:
-                n = int(np.prod(leaves[i].shape))
-                out[i] = red[off:off + n].reshape(
-                    leaves[i].shape).astype(leaves[i].dtype)
-                off += n
+            with jax.named_scope(obs.COMMS_BUCKET):
+                off = 0
+                for i in bucket:
+                    n = int(np.prod(leaves[i].shape))
+                    out[i] = red[off:off + n].reshape(
+                        leaves[i].shape).astype(leaves[i].dtype)
+                    off += n
         return jax.tree_util.tree_unflatten(treedef, out)
 
 

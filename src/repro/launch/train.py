@@ -19,6 +19,18 @@ import os
 import sys
 
 
+def start_count(text: str) -> tuple[int, int]:
+    """'START:COUNT' -> (START, COUNT), START >= 0 and COUNT >= 1."""
+    try:
+        start, count = (int(v) for v in text.split(":"))
+    except ValueError:
+        start = count = -1
+    if start < 0 or count < 1:
+        raise argparse.ArgumentTypeError(
+            f"{text!r}: expected START:COUNT, START >= 0 and COUNT >= 1")
+    return start, count
+
+
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="qwen3-8b")
@@ -56,6 +68,10 @@ def main(argv: list[str] | None = None) -> int:
                          "repairs the affected per-axis schedules in place "
                          "(CollectiveContext.hot_swap) and retries the same "
                          "step without restoring a checkpoint")
+    ap.add_argument("--trace-steps", type=start_count, default=None,
+                    metavar="START:COUNT",
+                    help="write a profiler trace of COUNT steps from step "
+                         "START into <ckpt-dir>/trace")
     argv = sys.argv[1:] if argv is None else list(argv)
     args = ap.parse_args(argv)
 
@@ -210,7 +226,8 @@ def main(argv: list[str] | None = None) -> int:
     os.makedirs(args.ckpt_dir, exist_ok=True)
     sup = TrainSupervisor(ckpt_dir=args.ckpt_dir,
                           ckpt_every=args.ckpt_every,
-                          on_link_fault=on_link_fault)
+                          on_link_fault=on_link_fault,
+                          trace_steps=args.trace_steps)
     state, final = sup.run(state=(params, opt), num_steps=args.steps,
                            step_fn=step_fn, log_every=1)
     print(f"done at step {final}; stragglers: {len(sup.monitor.flagged)}; "

@@ -19,6 +19,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from .common import ModelConfig, Params, dense_init, rms_norm
 
 
@@ -189,13 +190,15 @@ def mamba2_forward(p: Params, cfg: ModelConfig, x: jax.Array,
     xs = xs.reshape(x.shape[0], x.shape[1], h, pdim)
     a = -jnp.exp(p["A_log"].astype(jnp.float32))
 
-    if x.shape[1] % cfg.ssm_chunk == 0 and x.shape[1] >= cfg.ssm_chunk:
-        # intra-chunk math runs in the input dtype (C1: bf16 in training)
-        y, new_ssm = ssd_chunked(xs, dt, a, b, c, cfg.ssm_chunk, ssm_state)
-    else:
-        y, new_ssm = ssd_reference(xs.astype(jnp.float32), dt, a,
-                                   b.astype(jnp.float32),
-                                   c.astype(jnp.float32), ssm_state)
+    with jax.named_scope(obs.SSD):
+        if x.shape[1] % cfg.ssm_chunk == 0 and x.shape[1] >= cfg.ssm_chunk:
+            # intra-chunk math runs in the input dtype (C1: bf16 in training)
+            y, new_ssm = ssd_chunked(xs, dt, a, b, c, cfg.ssm_chunk,
+                                     ssm_state)
+        else:
+            y, new_ssm = ssd_reference(xs.astype(jnp.float32), dt, a,
+                                       b.astype(jnp.float32),
+                                       c.astype(jnp.float32), ssm_state)
     y = y.astype(jnp.float32) \
         + xs.astype(jnp.float32) * p["D"].astype(jnp.float32)[None, None, :, None]
     y = y.reshape(x.shape[0], x.shape[1], din).astype(x.dtype)

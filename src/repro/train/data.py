@@ -15,7 +15,10 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+from repro import obs
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,29 +69,30 @@ def make_global_batch(cfg: DataConfig, step: int, mesh: Mesh,
                       ) -> Dict[str, jax.Array]:
     """Assemble the sharded global batch; each addressable shard is
     materialised independently (multi-host safe)."""
-    specs = {"tokens": PartitionSpec(batch_axes)}
-    shapes = {"tokens": (cfg.global_batch, cfg.seq_len)}
-    if cfg.num_image_tokens:
-        specs["patch_embed"] = PartitionSpec(batch_axes)
-        shapes["patch_embed"] = (cfg.global_batch, cfg.num_image_tokens,
-                                 cfg.d_model)
-    if cfg.encoder_seq:
-        specs["audio_embed"] = PartitionSpec(batch_axes)
-        shapes["audio_embed"] = (cfg.global_batch, cfg.encoder_seq,
-                                 cfg.d_model)
+    with TraceAnnotation(obs.DATA_BATCH):
+        specs = {"tokens": PartitionSpec(batch_axes)}
+        shapes = {"tokens": (cfg.global_batch, cfg.seq_len)}
+        if cfg.num_image_tokens:
+            specs["patch_embed"] = PartitionSpec(batch_axes)
+            shapes["patch_embed"] = (cfg.global_batch, cfg.num_image_tokens,
+                                     cfg.d_model)
+        if cfg.encoder_seq:
+            specs["audio_embed"] = PartitionSpec(batch_axes)
+            shapes["audio_embed"] = (cfg.global_batch, cfg.encoder_seq,
+                                     cfg.d_model)
 
-    out = {}
-    for name, spec in specs.items():
-        sharding = NamedSharding(mesh, spec)
-        shape = shapes[name]
+        out = {}
+        for name, spec in specs.items():
+            sharding = NamedSharding(mesh, spec)
+            shape = shapes[name]
 
-        def cb(index, name=name, shape=shape):
-            rows = index[0]
-            lo = rows.start or 0
-            hi = rows.stop if rows.stop is not None else shape[0]
-            data = host_batch_slice(cfg, step, lo, hi)[name]
-            rest = index[1:]
-            return data[(slice(None),) + tuple(rest)]
+            def cb(index, name=name, shape=shape):
+                rows = index[0]
+                lo = rows.start or 0
+                hi = rows.stop if rows.stop is not None else shape[0]
+                data = host_batch_slice(cfg, step, lo, hi)[name]
+                rest = index[1:]
+                return data[(slice(None),) + tuple(rest)]
 
-        out[name] = jax.make_array_from_callback(shape, sharding, cb)
-    return out
+            out[name] = jax.make_array_from_callback(shape, sharding, cb)
+        return out

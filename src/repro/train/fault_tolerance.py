@@ -29,11 +29,14 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import os
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
+from repro import obs
 from . import checkpoint as ckpt
 
 
@@ -149,6 +152,9 @@ class TrainSupervisor:
     max_link_faults: int = 3
     monitor: StragglerMonitor = dataclasses.field(
         default_factory=StragglerMonitor)
+    #: (first step, number of steps) of which to write a profiler trace
+    #: into `<ckpt_dir>/trace`; None traces nothing
+    trace_steps: Optional[Tuple[int, int]] = None
 
     def run(self, *, state: Any, num_steps: int,
             step_fn: Callable[[int, Any], Tuple[Any, Dict[str, Any]]],
@@ -160,56 +166,89 @@ class TrainSupervisor:
         Any exception triggers restore-from-latest + replay (data is pure
         in step, so replayed steps are identical) — except a `LinkFault`
         with `on_link_fault` set, which repairs in place and retries the
-        step without touching checkpoints."""
+        step without touching checkpoints.
+
+        Each step is a profiler step marker holding its dispatch and wait
+        spans (names in `repro.obs`); a step after the first that compiles
+        is logged as compiled again."""
         step = start_step
         restarts = 0
         link_faults = 0
-        while step < num_steps:
-            try:
-                t0 = time.perf_counter()
-                state, metrics = step_fn(step, state)
-                # dispatch is asynchronous: time the step, not its enqueue
-                jax.block_until_ready((state, metrics))
-                dt = time.perf_counter() - t0
-                if self.monitor.observe(step, dt):
-                    log(f"[ft] straggler at step {step}: {dt:.3f}s "
-                        f"(ewma {self.monitor.ewma:.3f}s)")
-                if log_every and step % log_every == 0:
-                    loss = metrics.get("loss")
-                    log(f"step {step}: loss={float(loss):.4f} dt={dt:.3f}s"
-                        if loss is not None else f"step {step}: dt={dt:.3f}s")
-                step += 1
-                if step % self.ckpt_every == 0 or step == num_steps:
-                    ckpt.save_async(self.ckpt_dir, step, state)
-                    ckpt.gc_old(self.ckpt_dir, self.keep)
-            except KeyboardInterrupt:
-                raise
-            except LinkFault as e:
-                if self.on_link_fault is None:
-                    raise       # no repair path configured: a real crash
-                link_faults += 1
-                if link_faults > self.max_link_faults:
-                    raise RuntimeError(
-                        f"exceeded {self.max_link_faults} link faults") from e
-                log(f"[ft] link fault at step {step} ({e}); repairing "
-                    f"schedules in place (fault {link_faults}/"
-                    f"{self.max_link_faults})")
-                self.on_link_fault(e)
-                # state is intact (the step raised before committing):
-                # retry the same step on the repaired fabric, no restore
-            except Exception as e:  # noqa: BLE001 — any failure: restart
-                restarts += 1
-                if restarts > self.max_restarts:
-                    raise RuntimeError(
-                        f"exceeded {self.max_restarts} restarts") from e
-                ckpt.wait_pending()
-                last = ckpt.latest_step(self.ckpt_dir)
-                if last is None:
-                    raise RuntimeError("failure before first checkpoint") \
-                        from e
-                log(f"[ft] step {step} failed ({type(e).__name__}: {e}); "
-                    f"restoring step {last} (restart {restarts}/"
-                    f"{self.max_restarts})")
-                state, step = ckpt.restore(self.ckpt_dir, state, step=last)
+        first, count = self.trace_steps or (0, 0)
+        tracing = False
+        try:
+            while step < num_steps:
+                if count and not tracing and first <= step < first + count:
+                    jax.profiler.start_trace(
+                        os.path.join(self.ckpt_dir, "trace"))
+                    tracing = True
+                try:
+                    compiled = obs.compile_totals()
+                    t0 = time.perf_counter()
+                    with StepTraceAnnotation(obs.STEP, step_num=step):
+                        with TraceAnnotation(obs.DISPATCH):
+                            state, metrics = step_fn(step, state)
+                        # dispatch is asynchronous: time the step, not its
+                        # enqueue
+                        with TraceAnnotation(obs.WAIT):
+                            jax.block_until_ready((state, metrics))
+                    dt = time.perf_counter() - t0
+                    now = obs.compile_totals()
+                    if step > start_step and now != compiled:
+                        took = (obs.compile_seconds(now)
+                                - obs.compile_seconds(compiled))
+                        log(f"[obs] step {step} compiled again "
+                            f"({took:.3f} s)")
+                    if self.monitor.observe(step, dt):
+                        log(f"[ft] straggler at step {step}: {dt:.3f}s "
+                            f"(ewma {self.monitor.ewma:.3f}s)")
+                    if log_every and step % log_every == 0:
+                        loss = metrics.get("loss")
+                        log(f"step {step}: loss={float(loss):.4f} "
+                            f"dt={dt:.3f}s" if loss is not None
+                            else f"step {step}: dt={dt:.3f}s")
+                    step += 1
+                    if step % self.ckpt_every == 0 or step == num_steps:
+                        with TraceAnnotation(obs.CHECKPOINT):
+                            ckpt.save_async(self.ckpt_dir, step, state)
+                            ckpt.gc_old(self.ckpt_dir, self.keep)
+                except KeyboardInterrupt:
+                    raise
+                except LinkFault as e:
+                    if self.on_link_fault is None:
+                        raise   # no repair path configured: a real crash
+                    link_faults += 1
+                    if link_faults > self.max_link_faults:
+                        raise RuntimeError(
+                            f"exceeded {self.max_link_faults} link "
+                            f"faults") from e
+                    log(f"[ft] link fault at step {step} ({e}); repairing "
+                        f"schedules in place (fault {link_faults}/"
+                        f"{self.max_link_faults})")
+                    with TraceAnnotation(obs.REPAIR):
+                        self.on_link_fault(e)
+                    # state is intact (the step raised before committing):
+                    # retry the same step on the repaired fabric, no restore
+                except Exception as e:  # noqa: BLE001 — any failure: restart
+                    restarts += 1
+                    if restarts > self.max_restarts:
+                        raise RuntimeError(
+                            f"exceeded {self.max_restarts} restarts") from e
+                    ckpt.wait_pending()
+                    last = ckpt.latest_step(self.ckpt_dir)
+                    if last is None:
+                        raise RuntimeError("failure before first "
+                                           "checkpoint") from e
+                    log(f"[ft] step {step} failed ({type(e).__name__}: "
+                        f"{e}); restoring step {last} (restart "
+                        f"{restarts}/{self.max_restarts})")
+                    state, step = ckpt.restore(self.ckpt_dir, state,
+                                               step=last)
+                if tracing and step >= first + count:
+                    jax.profiler.stop_trace()
+                    tracing = False
+        finally:
+            if tracing:
+                jax.profiler.stop_trace()
         ckpt.wait_pending()
         return state, step

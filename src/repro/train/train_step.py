@@ -18,6 +18,7 @@ from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.models.model_zoo import Model
 from .optimizer import AdamWConfig, AdamWState, adamw_update, init_adamw
 
@@ -49,8 +50,9 @@ def loss_and_grad(model: Model, params, batch,
                   cfg: TrainConfig) -> Tuple[jax.Array, Any, jax.Array]:
     """Returns (loss, grads, raw_token_loss); microbatched if configured."""
     def loss_fn(p, b):
-        cast = cast_params(p, cfg.compute_dtype, cfg.cast_sharding)
-        total, token_loss = model.loss(cast, b)
+        with jax.named_scope(obs.FORWARD):
+            cast = cast_params(p, cfg.compute_dtype, cfg.cast_sharding)
+            total, token_loss = model.loss(cast, b)
         return total, token_loss
 
     if cfg.microbatches <= 1:
@@ -95,10 +97,12 @@ def make_train_step(model: Model, cfg: TrainConfig,
                    ) -> Tuple[Any, AdamWState, Dict[str, jax.Array]]:
         loss, grads, tok = loss_and_grad(model, params, batch, cfg)
         if grad_reduce is not None:
-            grads = grad_reduce(grads)
-            loss = grad_reduce(loss)  # average the scalar too
-        new_params, new_state, metrics = adamw_update(
-            cfg.optimizer, grads, opt_state, params)
+            with jax.named_scope(obs.GRAD_REDUCE):
+                grads = grad_reduce(grads)
+                loss = grad_reduce(loss)  # average the scalar too
+        with jax.named_scope(obs.ADAMW):
+            new_params, new_state, metrics = adamw_update(
+                cfg.optimizer, grads, opt_state, params)
         metrics = dict(metrics, loss=loss, token_loss=tok)
         return new_params, new_state, metrics
 
