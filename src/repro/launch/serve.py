@@ -137,7 +137,11 @@ def main(argv: list[str] | None = None) -> int:
                 uid=i,
                 prompt=rng.integers(1, cfg.vocab_size, plen, dtype=np.int32),
                 max_new_tokens=args.new_tokens))
-        for c in engine.run():
+        # traced under the mesh, so that code which cannot be partitioned
+        # (the SSD's Pallas kernels) sees a tensor-parallel step
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            completions = engine.run()
+        for c in completions:
             print(f"req {c.uid}: {c.prompt_len} prompt -> "
                   f"{len(c.tokens) - c.prompt_len} new tokens "
                   f"({c.latency_s * 1e3:.0f} ms batch)")

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import os
 import sys
 
@@ -186,9 +187,18 @@ def main(argv: list[str] | None = None) -> int:
                                     check_vma=False)
             with mesh:
                 return jax.jit(step_sm, donate_argnums=(0, 1))
+        train_step = make_train_step(model, tc)
+
+        @functools.wraps(train_step)
+        def gspmd_step(params, opt_state, batch):
+            # traced under the mesh GSPMD partitions it over, so that code
+            # which cannot be partitioned (the SSD's Pallas kernels) sees it
+            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+                return train_step(params, opt_state, batch)
+
         with mesh:
             return jax.jit(
-                make_train_step(model, tc),
+                gspmd_step,
                 in_shardings=(to_named(p_spec, mesh), to_named(o_spec, mesh),
                               to_named(b_spec, mesh)),
                 out_shardings=(to_named(p_spec, mesh), to_named(o_spec, mesh),

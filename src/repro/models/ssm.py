@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
+from repro.kernels import ssd_scan
 from .common import ModelConfig, Params, dense_init, rms_norm
 
 
@@ -60,18 +61,105 @@ def _segsum(x: jax.Array) -> jax.Array:
     return jnp.where(mask, diff, -jnp.inf)
 
 
+def ssd_kernel_fits(x: jax.Array, b: jax.Array, chunk: int) -> bool:
+    """Whether `ssd_chunked` runs the Pallas kernels here: a TPU, whole
+    chunks of at least `ssd_scan.MIN_CHUNK` steps (shorter ones keep XLA,
+    which is faster there), widths the kernels tile, and a computation
+    that is not partitioned across devices (a Pallas call cannot be).
+    Placement is read from the mesh the scan is traced under: none (a jit
+    placed by its arguments alone), a mesh of one device, or a `shard_map`
+    body over every axis.  A program GSPMD partitions over several devices
+    is traced under its mesh (`jax.sharding.use_abstract_mesh`, as
+    `repro.launch.train` does) and keeps XLA."""
+    if (jax.default_backend() != "tpu" or x.shape[1] % chunk
+            or chunk < ssd_scan.MIN_CHUNK):
+        return False
+    mesh = jax.sharding.get_abstract_mesh()
+    if not (mesh.empty or mesh.size == 1 or mesh.are_all_axes_manual):
+        return False
+    _, _, h, p = x.shape
+    return ssd_scan.kernel_fits(h, p, b.shape[-1], chunk)
+
+
 def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
                 c: jax.Array, chunk: int,
-                init_state: Optional[jax.Array] = None
+                init_state: Optional[jax.Array] = None,
+                impl: Optional[str] = None
                 ) -> Tuple[jax.Array, jax.Array]:
     """Chunked SSD.
     x: [B,S,H,P], dt: [B,S,H] (>0), a: [H] (<0), b,c: [B,S,N].
     Returns (y [B,S,H,P], final_state [B,H,P,N] float32).
 
-    Mixed precision (perf iteration C1, EXPERIMENTS.md §Perf): decay terms
-    (exp/cumsum) and the inter-chunk state CARRY stay float32; the large
-    intra-chunk einsums and the per-chunk emitted states run in the input
-    dtype (bf16 in training) — the state tensors dominate HBM traffic."""
+    The intra-chunk block runs as the Pallas kernels of
+    `repro.kernels.ssd_scan` where `ssd_kernel_fits`, else in XLA.  `impl`
+    forces a path: "xla", "kernel", or "interpret" (the kernels under the
+    Pallas interpreter, for tests off the chip).  Each call is counted by
+    path at trace time (`obs.count_ssd`).
+
+    Mixed precision: decay terms (exp/cumsum) and the inter-chunk state
+    CARRY stay float32; the large intra-chunk products run in the input
+    dtype (bf16 in training) with f32 accumulation.  The XLA path also
+    emits the per-chunk states in the input dtype; the kernels emit them
+    in f32."""
+    if impl is None:
+        impl = "kernel" if ssd_kernel_fits(x, b, chunk) else "xla"
+    obs.count_ssd("xla" if impl == "xla" else "kernel")
+    if impl != "xla":
+        return _ssd_chunked_kernel(x, dt, a, b, c, chunk, init_state,
+                                   interpret=impl == "interpret")
+    return _ssd_chunked_xla(x, dt, a, b, c, chunk, init_state)
+
+
+def _inter_chunk(states, chunk_decay, init_state, cdt):
+    """Inter-chunk recurrence: states [B,L,H,P,N], chunk_decay [B,L,H] f32.
+    Returns (final f32, entering [B,L,H,P,N] in cdt: each chunk's state
+    before it)."""
+    bs, _, h, p, n = states.shape
+    h0 = init_state.astype(jnp.float32) if init_state is not None else \
+        jnp.zeros((bs, h, p, n), jnp.float32)
+
+    def step(carry, inp):
+        st, dec = inp                                        # [B,H,P,N],[B,H]
+        new = carry * dec[..., None, None] + st.astype(jnp.float32)
+        return new, carry.astype(cdt)                        # emit entering
+
+    final, entering = jax.lax.scan(
+        step, h0, (jnp.moveaxis(states, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)))
+    return final, jnp.moveaxis(entering, 0, 1)
+
+
+def _ssd_chunked_kernel(x, dt, a, b, c, chunk, init_state, interpret):
+    bs, s, h, p = x.shape
+    n, w = b.shape[-1], h * p
+    cdt = x.dtype
+    l = s // chunk
+    c = c.astype(cdt)
+    # heads side by side in the lanes, as the projection made them: no
+    # relayout of x, y or their gradients around the kernels
+    with jax.named_scope(obs.SSD_KERNEL):
+        y_diag, states, cum = ssd_scan.ssd_chunk_intra(
+            x.reshape(bs, s, w), dt.astype(jnp.float32),
+            a.astype(jnp.float32), b.astype(cdt), c, chunk=chunk,
+            interpret=interpret)
+    cum = cum.reshape(bs, h, l, chunk)                        # f32
+    final, entering = _inter_chunk(
+        states, jnp.moveaxis(jnp.exp(cum[..., -1]), 1, 2), init_state, cdt)
+    # off-diagonal: prior state read out through intra-chunk decay, in the
+    # lanes' layout (an exact 0/1 product spreads each head's decay over
+    # its lanes: a broadcast there would be relaid out)
+    y_off = jnp.einsum("blqn,blnw->blqw", c.reshape(bs, l, chunk, n),
+                       jnp.swapaxes(entering.reshape(bs, l, w, n), 2, 3),
+                       preferred_element_type=jnp.float32)
+    spread = (jnp.arange(w)[None, :] // p == jnp.arange(h)[:, None])
+    decay = jnp.einsum("blqh,hw->blqw",
+                       jnp.exp(jnp.moveaxis(cum, 1, 3)).astype(cdt),
+                       spread.astype(cdt),
+                       precision=jax.lax.Precision.HIGHEST)
+    y = y_diag + (y_off.astype(cdt) * decay).reshape(bs, s, w)
+    return y.reshape(bs, s, h, p), final
+
+
+def _ssd_chunked_xla(x, dt, a, b, c, chunk, init_state):
     bs, s, h, p = x.shape
     n = b.shape[-1]
     cdt = x.dtype                                             # compute dtype
@@ -101,17 +189,7 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
 
     # 3. inter-chunk recurrence (f32 carry; emits in compute dtype)
     chunk_decay = jnp.exp(da_cs[:, :, -1, :])                 # [B,L,H] f32
-    h0 = init_state.astype(jnp.float32) if init_state is not None else \
-        jnp.zeros((bs, h, p, n), jnp.float32)
-
-    def step(carry, inp):
-        st, dec = inp                                        # [B,H,P,N],[B,H]
-        new = carry * dec[..., None, None] + st.astype(jnp.float32)
-        return new, carry.astype(cdt)                        # emit entering
-
-    final, entering = jax.lax.scan(
-        step, h0, (jnp.moveaxis(states, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)))
-    entering = jnp.moveaxis(entering, 0, 1)                   # [B,L,H,P,N]
+    final, entering = _inter_chunk(states, chunk_decay, init_state, cdt)
 
     # 4. off-diagonal: prior state read out through intra-chunk decay
     state_decay = jnp.exp(da_cs).astype(cdt)                  # [B,L,Q,H]

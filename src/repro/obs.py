@@ -16,6 +16,9 @@ counter.
   (`jax.monitoring`), from import on, and `compile_totals` reads it, in
   all or up to a moment on the wall clock (`time.time_ns`, the clock of a
   profiler trace's start time).
+* The SSD path counter counts, when a program is traced, each SSD scan by
+  the path it took (`count_ssd`: the Pallas kernels or XLA);
+  `ssd_paths` reads it.
 
 README.md ("Tracing a training run") says what each name covers.
 """
@@ -32,6 +35,7 @@ FORWARD = "train.forward"
 GRAD_REDUCE = "train.grad_reduce"
 ADAMW = "train.adamw"
 SSD = "ssm.ssd"
+SSD_KERNEL = "ssm.ssd.kernel"
 COMMS_GATHER = "comms.gather"
 COMMS_PERMUTE = "comms.permute"
 COMMS_SCATTER = "comms.scatter"
@@ -88,6 +92,18 @@ def compile_totals(before_ns: Optional[int] = None) -> Dict[str, Totals]:
     return {p: Totals(count[p], seconds[p]) for p in count}
 
 
+def count_ssd(path: str) -> None:
+    """Count one SSD scan traced on `path` ("kernel" or "xla")."""
+    with _LOCK:
+        _SSD_PATHS[path] = _SSD_PATHS.get(path, 0) + 1
+
+
+def ssd_paths() -> Dict[str, int]:
+    """SSD scans traced since this module was first imported, by path."""
+    with _LOCK:
+        return dict(_SSD_PATHS)
+
+
 def compile_seconds(totals: Dict[str, Totals]) -> float:
     """Wall seconds spent compiling: tracing, lowering and the backend
     compile (which holds any persistent-cache read)."""
@@ -101,4 +117,5 @@ if "_EVENTS" not in globals():
     _LOCK = threading.Lock()
     _TOTALS = {p: Totals(0, 0.0) for p in COMPILE_EVENTS.values()}
     _EVENTS: list = []       # (time.time_ns() at its end, phase, seconds)
+    _SSD_PATHS: Dict[str, int] = {}
     jax.monitoring.register_event_duration_secs_listener(_record)

@@ -170,7 +170,8 @@ class TrainSupervisor:
 
         Each step is a profiler step marker holding its dispatch and wait
         spans (names in `repro.obs`); a step after the first that compiles
-        is logged as compiled again."""
+        is logged as compiled again, and a step that compiles logs the SSD
+        scans it traced by path."""
         step = start_step
         restarts = 0
         link_faults = 0
@@ -184,6 +185,7 @@ class TrainSupervisor:
                     tracing = True
                 try:
                     compiled = obs.compile_totals()
+                    ssd = obs.ssd_paths()
                     t0 = time.perf_counter()
                     with StepTraceAnnotation(obs.STEP, step_num=step):
                         with TraceAnnotation(obs.DISPATCH):
@@ -199,6 +201,13 @@ class TrainSupervisor:
                                 - obs.compile_seconds(compiled))
                         log(f"[obs] step {step} compiled again "
                             f"({took:.3f} s)")
+                    traced = {k: v - ssd.get(k, 0)
+                              for k, v in obs.ssd_paths().items()
+                              if v != ssd.get(k, 0)}
+                    if traced:
+                        log(f"[obs] step {step} traced the SSD scan: "
+                            + ", ".join(f"{k} {v}" for k, v in
+                                        sorted(traced.items())))
                     if self.monitor.observe(step, dt):
                         log(f"[ft] straggler at step {step}: {dt:.3f}s "
                             f"(ewma {self.monitor.ewma:.3f}s)")

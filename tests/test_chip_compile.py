@@ -80,19 +80,44 @@ def test_flash_attention_compiles_head_dim_128_seq_2048(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _ssd_shapes(one_chip, bs, s, h, p, n):
+    return (_shape((bs, s, h * p), jnp.bfloat16, one_chip),
+            _shape((bs, s, h), jnp.float32, one_chip),
+            _shape((h,), jnp.float32, one_chip),
+            _shape((bs, s, n), jnp.bfloat16, one_chip),
+            _shape((bs, s, n), jnp.bfloat16, one_chip))
+
+
 def test_ssd_chunk_intra_compiles_at_mamba2_widths(one_chip):
     """mamba2-780m: 48 heads of dim 64, state 128, chunk 512."""
     from repro.kernels.ssd_scan import ssd_chunk_intra
-    bh, s, p, n, q = 48, 2048, 64, 128, 512
     compiled = jax.jit(
         lambda x, dt, a, b, c: ssd_chunk_intra(
-            x, dt, a, b, c, chunk=q, interpret=False)).lower(
-        _shape((bh, s, p), jnp.bfloat16, one_chip),
-        _shape((bh, s), jnp.bfloat16, one_chip),
-        _shape((bh,), jnp.float32, one_chip),
-        _shape((bh, s, n), jnp.bfloat16, one_chip),
-        _shape((bh, s, n), jnp.bfloat16, one_chip)).compile()
+            x, dt, a, b, c, chunk=512, interpret=False)).lower(
+        *_ssd_shapes(one_chip, 1, 2048, 48, 64, 128)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("widths", [
+    (48, 64, 128, 512),     # mamba2-780m: heads, head_dim, state, chunk
+    (64, 64, 64, 256),      # zamba2-1.2b's Mamba2 layers
+])
+def test_ssd_kernels_compile_forward_and_backward(one_chip, widths):
+    """Both SSD kernels, as one layer's gradient runs them at S 2048: the
+    forward and the backward fit v5e's VMEM and tiling."""
+    from repro.kernels.ssd_scan import kernel_fits, ssd_chunk_intra
+    h, p, n, q = widths
+    assert kernel_fits(h, p, n, q)
+
+    def loss(x, dt, a, b, c):
+        y, states, cum = ssd_chunk_intra(x, dt, a, b, c, chunk=q)
+        return (jnp.sum(y.astype(jnp.float32)) + jnp.sum(states)
+                + jnp.sum(cum))
+
+    step = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))
+    compiled = jax.jit(step).lower(
+        *_ssd_shapes(one_chip, 1, 2048, h, p, n)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2
 
 
 # ---------------------------------------------------------------------- #

@@ -81,6 +81,45 @@ def test_ssd_scope_in_forward_remat_and_backward(step_hlo):
     assert not any(obs.SSD in n for n in split["optimizer"])
 
 
+@pytest.fixture(scope="module")
+def kernel_step():
+    """The tiny step's optimized HLO with the SSD forced onto the Pallas
+    kernels (interpreted, as off the chip), and the SSD paths its trace
+    counted."""
+    import functools
+    from repro.models import ssm
+    cfg = reduced_config("mamba2-780m")
+    model = build_model(cfg, remat=True)
+    params, opt = init_train_state(model, jax.random.PRNGKey(0))
+    step = make_train_step(model, TrainConfig(compute_dtype=jnp.bfloat16))
+    batch = {"tokens": jnp.zeros((2, 2 * cfg.ssm_chunk), jnp.int32)}
+    before = obs.ssd_paths()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ssm, "ssd_chunked", functools.partial(
+            ssm.ssd_chunked, impl="interpret"))
+        hlo = jax.jit(step).lower(params, opt, batch).compile().as_text()
+    after = obs.ssd_paths()
+    return hlo, {k: v - before.get(k, 0) for k, v in after.items()}
+
+
+def test_ssd_kernel_scope_in_forward_remat_and_backward(kernel_step):
+    hlo, _ = kernel_step
+    split = phases(op_names(hlo))
+    for phase in ("forward", "remat", "backward"):
+        names = [n for n in split[phase] if obs.SSD_KERNEL in n]
+        assert names, phase
+        # nested in the SSD scope, so the SSD's reader counts it
+        assert all(f"{obs.SSD}/{obs.SSD_KERNEL}" in n for n in names)
+
+
+def test_ssd_path_counter_counts_the_traced_path(kernel_step, step_hlo):
+    _, traced = kernel_step
+    assert traced.get("kernel", 0) >= 1 and not traced.get("xla")
+    # off the chip the automatic choice is the XLA path
+    assert obs.ssd_paths().get("xla", 0) >= 1
+    assert obs.SSD_KERNEL not in step_hlo
+
+
 def test_tree_all_reduce_carries_comms_scopes():
     code = """
         import jax, jax.numpy as jnp, numpy as np
@@ -165,6 +204,33 @@ def test_supervisor_trace_holds_step_markers_and_spans(tmp_path):
     assert events.get(obs.DATA_BATCH) == 2    # the fault precedes the batch
     assert events.get(obs.CHECKPOINT) == 1       # after step 2, as step 3
     assert events.get(obs.REPAIR) == 1
+
+
+def test_supervisor_logs_the_ssd_paths_a_compile_traced(tmp_path):
+    cfg = reduced_config("mamba2-780m")
+    model = build_model(cfg, remat=True)
+    params, opt = init_train_state(model, jax.random.PRNGKey(0))
+    step_jit = jax.jit(make_train_step(model, TrainConfig()))
+    dc = DataConfig(vocab_size=cfg.vocab_size, seq_len=2 * cfg.ssm_chunk,
+                    global_batch=2)
+    mesh = jax.sharding.Mesh(jax.devices()[:1], ("data",))
+
+    def step_fn(step, state):
+        p, o, m = step_jit(*state, make_global_batch(dc, step, mesh))
+        return (p, o), m
+
+    logs = []
+    sup = TrainSupervisor(ckpt_dir=str(tmp_path), ckpt_every=100)
+    sup.run(state=(params, opt), num_steps=3, step_fn=step_fn,
+            log=logs.append, log_every=0)
+    traced = [s for s in logs if "traced the SSD" in s]
+    # once per compile (the first step's, and any again), on the XLA path
+    # off the chip
+    again = [s.split()[2] for s in logs if "compiled again" in s]
+    assert [s.split()[2] for s in traced] == ["0"] + again, logs
+    for s in traced:
+        assert re.fullmatch(r"\[obs\] step \d+ traced the SSD scan: "
+                            r"xla \d+", s), s
 
 
 def test_compile_counter_counts_a_first_compile():
