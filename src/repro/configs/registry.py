@@ -27,7 +27,7 @@ ARCHS: Dict[str, ModelConfig] = {
 }
 
 # archs whose decode state stays bounded (or O(1)) at 500k context
-_LONG_CONTEXT_OK = {"mixtral-8x7b", "zamba2-1.2b", "mamba2-780m"}
+_LONG_CONTEXT_OK = {"mixtral-8x7b", "mamba2-780m"}
 
 _SKIP_REASONS = {
     "mistral-nemo-12b": "pure full attention: unbounded 500k KV per layer",
@@ -37,6 +37,9 @@ _SKIP_REASONS = {
     "paligemma-3b": "full-attention prefix LM: unbounded 500k KV",
     "gemma2-2b": "alternating global layers are full attention at 500k",
     "whisper-medium": "decoder hard-capped at 448 positions by design",
+    "zamba2-1.2b": "the shared block is full attention: a 500k KV cache at "
+                   "each of its six sites, far past the published "
+                   "max_position_embeddings of 4096",
 }
 
 
@@ -81,7 +84,7 @@ def reduced_config(name: str, **overrides) -> ModelConfig:
     if base.ssm_state_dim:
         small.update(ssm_state_dim=16, ssm_head_dim=16, ssm_chunk=16)
     if base.hybrid_attn_every:
-        small.update(hybrid_attn_every=2, num_layers=4)
+        small.update(hybrid_attn_every=2, num_layers=5, adapter_rank=8)
     if base.is_encoder_decoder:
         small.update(encoder_layers=2, encoder_seq=64)
     if base.num_image_tokens:

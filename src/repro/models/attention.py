@@ -10,15 +10,16 @@ Three execution paths:
                 lax.scan over KV blocks (flash attention expressed in XLA;
                 O(block^2) memory).  For sliding-window attention only the
                 window-adjacent KV blocks are visited, so compute is
-                O(S * window) — this is what makes the long_500k cells of
-                mixtral/zamba2 tractable.
+                O(S * window) — this is what makes mixtral's long_500k
+                cell tractable.
 * kernel      — the Pallas flash kernel (repro.kernels) on TPU; registered
                 via `set_flash_impl`, validated against the paths above.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, ContextManager, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -75,13 +76,16 @@ def ring_positions(index: jax.Array, cache_len: int) -> jax.Array:
 
 
 def init_attention(key: jax.Array, cfg: ModelConfig,
-                   dtype=jnp.float32) -> Params:
+                   dtype=jnp.float32, d_in: Optional[int] = None) -> Params:
+    """q, k and v project `d_in` (d_model unless given) onto the heads; o
+    projects back onto d_model."""
     hd = cfg.hd
+    d_in = d_in or cfg.d_model
     ks = jax.random.split(key, 4)
     p = {
-        "wq": dense_init(ks[0], (cfg.d_model, cfg.num_heads * hd), dtype),
-        "wk": dense_init(ks[1], (cfg.d_model, cfg.num_kv_heads * hd), dtype),
-        "wv": dense_init(ks[2], (cfg.d_model, cfg.num_kv_heads * hd), dtype),
+        "wq": dense_init(ks[0], (d_in, cfg.num_heads * hd), dtype),
+        "wk": dense_init(ks[1], (d_in, cfg.num_kv_heads * hd), dtype),
+        "wv": dense_init(ks[2], (d_in, cfg.num_kv_heads * hd), dtype),
         "wo": dense_init(ks[3], (cfg.num_heads * hd, cfg.d_model), dtype),
     }
     if cfg.qk_norm:
@@ -94,14 +98,20 @@ def init_attention(key: jax.Array, cfg: ModelConfig,
 # core attend
 # ---------------------------------------------------------------------- #
 
+def _scaled(logits: jax.Array, d: int, scale: Optional[float]) -> jax.Array:
+    """Scores times `scale`, d^-1/2 unless given."""
+    return logits / jnp.sqrt(d) if scale is None else logits * scale
+
+
 def _direct_attend(q, k, v, q_pos, kv_pos, spec: MaskSpec,
-                   logit_cap: Optional[float]) -> jax.Array:
+                   logit_cap: Optional[float],
+                   scale: Optional[float] = None) -> jax.Array:
     b, s, h, d = q.shape
     hkv = k.shape[2]
     g = h // hkv
     qg = q.reshape(b, s, hkv, g, d)
     logits = jnp.einsum("bshgd,bthd->bhgst", qg, k).astype(jnp.float32)
-    logits = softcap(logits / jnp.sqrt(d), logit_cap)
+    logits = softcap(_scaled(logits, d, scale), logit_cap)
     ok = spec.allowed(q_pos, kv_pos)                  # [B,S,T] or [S,T]
     if ok.ndim == 2:
         ok = ok[None]
@@ -113,6 +123,7 @@ def _direct_attend(q, k, v, q_pos, kv_pos, spec: MaskSpec,
 
 def _blockwise_attend(q, k, v, q_pos, kv_pos, spec: MaskSpec,
                       logit_cap: Optional[float],
+                      scale: Optional[float] = None,
                       block_q: int = BLOCK_Q,
                       block_kv: int = BLOCK_KV) -> jax.Array:
     """Online-softmax flash attention in XLA.  Sliding-window masks visit
@@ -184,7 +195,7 @@ def _blockwise_attend(q, k, v, q_pos, kv_pos, spec: MaskSpec,
             kpj = kp_r[jj]
             logits = jnp.einsum("bshgd,bthd->bhgst", qg, kj
                                 ).astype(jnp.float32)
-            logits = softcap(logits / jnp.sqrt(d), logit_cap)
+            logits = softcap(_scaled(logits, d, scale), logit_cap)
             ok = spec.allowed(qpi, kpj)
             logits = jnp.where(ok[None, None, None], logits, NEG_INF)
             blk_max = logits.max(axis=-1)                     # [B,hkv,g,bq]
@@ -218,15 +229,21 @@ def _blockwise_attend(q, k, v, q_pos, kv_pos, spec: MaskSpec,
 
 def attend(q: jax.Array, k: jax.Array, v: jax.Array,
            q_pos: jax.Array, kv_pos: jax.Array, spec: MaskSpec,
-           logit_cap: Optional[float] = None) -> jax.Array:
-    """q: [B,S,H,D], k/v: [B,T,Hkv,D], positions: [S]/[T] int."""
+           logit_cap: Optional[float] = None,
+           scale: Optional[float] = None) -> jax.Array:
+    """q: [B,S,H,D], k/v: [B,T,Hkv,D], positions: [S]/[T] int; scores are
+    scaled by `scale`, D^-1/2 unless given."""
     t = k.shape[1]
     s = q.shape[1]
     if _FLASH_IMPL is not None and s > 1:
+        if scale is not None:      # the kernel scales by D^-1/2
+            q = q * jnp.asarray(scale * q.shape[-1] ** 0.5, q.dtype)
         return _FLASH_IMPL(q, k, v, q_pos, kv_pos, spec, logit_cap)
     if s == 1 or max(s, t) <= BLOCKWISE_THRESHOLD:
-        return _direct_attend(q, k, v, q_pos, kv_pos, spec, logit_cap)
-    return _blockwise_attend(q, k, v, q_pos, kv_pos, spec, logit_cap)
+        return _direct_attend(q, k, v, q_pos, kv_pos, spec, logit_cap,
+                              scale)
+    return _blockwise_attend(q, k, v, q_pos, kv_pos, spec, logit_cap,
+                             scale)
 
 
 # ---------------------------------------------------------------------- #
@@ -241,8 +258,11 @@ def attention_forward(
         cache_index: Optional[jax.Array] = None,
         cache_positions: Optional[jax.Array] = None,
         logit_cap: Optional[float] = None,
+        lora: Optional[Dict[str, Tuple[jax.Array, jax.Array]]] = None,
+        scale: Optional[float] = None,
+        core_scope: Optional[ContextManager] = None,
 ) -> Tuple[jax.Array, Optional[Tuple[jax.Array, jax.Array]]]:
-    """x: [B,S,d]; positions: [S] int32.
+    """x: [B,S,d_in]; positions: [S] int32.
 
     * training / prefill: cache=None.
     * decode: cache = (k_cache, v_cache) [B,Tmax,Hkv,D]; new rows written at
@@ -250,14 +270,26 @@ def attention_forward(
       attention runs over the cache with `cache_positions` (defaults to
       arange) giving each row's absolute position for masking.
     * cross-attention: kv_override = precomputed (k, v) (no rope).
+    * lora: {"q" | "k" | "v": (A, B)} adds x A B to that projection.
+    * scale: the scores' scale, head_dim^-1/2 unless given.
+    * core_scope: a context (a named scope) that scores, mask, softmax and
+      PV run in.
     """
     hd = cfg.hd
     b, s, _ = x.shape
-    from .common import constrain
-    q = (x @ p["wq"]).reshape(b, s, cfg.num_heads, hd)
+    lora = lora or {}
+
+    def proj(name, heads):
+        y = x @ p["w" + name]
+        if name in lora:
+            a, bm = lora[name]
+            y = y + (x @ a) @ bm
+        return y.reshape(b, s, heads, hd)
+
+    q = proj("q", cfg.num_heads)
     if kv_override is None:
-        k = (x @ p["wk"]).reshape(b, s, cfg.num_kv_heads, hd)
-        v = (x @ p["wv"]).reshape(b, s, cfg.num_kv_heads, hd)
+        k = proj("k", cfg.num_kv_heads)
+        v = proj("v", cfg.num_kv_heads)
         # (perf iteration A3 tried pinning the SP->TP boundary here, before
         # the f32 rope segment — measured +3.7% collective bytes; reverted.)
         if cfg.qk_norm:
@@ -299,6 +331,7 @@ def attention_forward(
     elif kv_override is not None:
         kv_pos = jnp.arange(k.shape[1])
 
-    out = attend(q, k.astype(q.dtype), v.astype(q.dtype),
-                 positions, kv_pos, spec, logit_cap)
+    with core_scope or contextlib.nullcontext():
+        out = attend(q, k.astype(q.dtype), v.astype(q.dtype),
+                     positions, kv_pos, spec, logit_cap, scale)
     return out.reshape(b, s, cfg.num_heads * hd) @ p["wo"], new_cache

@@ -8,6 +8,7 @@ over layers (O(1) HLO size — essential for compiling 40-layer models for a
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -52,8 +53,13 @@ class ModelConfig:
     ssm_chunk: int = 64
     ssm_conv_width: int = 4
     ssm_expand: int = 2
-    # hybrid (zamba2): shared attention block every k ssm layers
+    # hybrid (zamba2): the shared transformer block runs before the Mamba2
+    # layer at every `hybrid_attn_every`-th layer (ids k, 2k, ...), with
+    # per-site rank-`adapter_rank` adapters (on q, k and v too when
+    # `shared_attn_adapters`)
     hybrid_attn_every: int = 0
+    adapter_rank: int = 0
+    shared_attn_adapters: bool = False
     # encoder-decoder (whisper)
     is_encoder_decoder: bool = False
     encoder_layers: int = 0
@@ -78,15 +84,54 @@ class ModelConfig:
     def q_groups(self) -> int:
         return self.num_heads // max(self.num_kv_heads, 1)
 
+    @property
+    def num_shared_sites(self) -> int:
+        """Hybrid layers: ids hybrid_attn_every * k (k >= 1) below
+        num_layers."""
+        if not self.hybrid_attn_every:
+            return 0
+        return (self.num_layers - 1) // self.hybrid_attn_every
+
+    def _mamba2_layer_params(self) -> int:
+        """One Mamba2 layer: pre-norm, in_proj, conv (with bias), A, D,
+        dt bias, gated-norm weight and out_proj."""
+        d, n = self.d_model, self.ssm_state_dim
+        din = self.ssm_expand * d
+        h = self.ssm_num_heads or din // self.ssm_head_dim
+        conv = din + 2 * n
+        return (d + d * (2 * din + 2 * n + h) + (self.ssm_conv_width + 1)
+                * conv + 3 * h + din + din * d)
+
+    def _shared_block_params(self) -> int:
+        """The hybrid family's shared block: attention over [h, embedding]
+        (width 2 d) and the gated MLP, with their norms."""
+        d, hd = self.d_model, self.hd
+        q, kv = self.num_heads * hd, self.num_kv_heads * hd
+        return 2 * d + 2 * d * (q + 2 * kv) + q * d + d + 3 * d * self.d_ff
+
+    def _site_params(self) -> int:
+        """Per hybrid layer: its `linear` and adapters."""
+        d, r, hd = self.d_model, self.adapter_rank, self.hd
+        n = d * d + r * (d + 2 * self.d_ff)
+        if self.shared_attn_adapters:
+            n += r * (3 * 2 * d + (self.num_heads + 2 * self.num_kv_heads)
+                      * hd)
+        return n
+
     def param_count(self) -> int:
-        """Approximate parameter count (reported in roofline MODEL_FLOPS)."""
+        """Parameter count (reported in roofline MODEL_FLOPS); exact for the
+        ssm and hybrid families, whose layers are counted leaf by leaf, and
+        the stored total for hybrid (the shared block once, where a site
+        applies it)."""
         d, v, l = self.d_model, self.vocab_size, self.num_layers
         emb = v * d * (1 if self.tie_embeddings else 2)
         if self.family == "ssm":
-            din = self.ssm_expand * d
-            per = (d * (2 * din + 2 * self.ssm_state_dim) +  # in_proj approx
-                   din * d + din)
-            return emb + l * per
+            return emb + l * self._mamba2_layer_params() + d
+        if self.family == "hybrid":
+            n = self.num_shared_sites
+            return (emb + l * self._mamba2_layer_params() + d
+                    + n * self._site_params()
+                    + (self._shared_block_params() if n else 0))
         att = d * self.num_heads * self.hd + 2 * d * self.num_kv_heads * self.hd \
             + self.num_heads * self.hd * d
         if self.num_experts:
@@ -102,7 +147,11 @@ class ModelConfig:
         return total
 
     def active_param_count(self) -> int:
-        """Active params per token (MoE: only routed-in experts count)."""
+        """Active params per token (MoE: only routed-in experts count;
+        hybrid: the shared block once for each site that applies it)."""
+        if self.family == "hybrid":
+            return self.param_count() + max(self.num_shared_sites - 1, 0) \
+                * self._shared_block_params()
         if not self.num_experts:
             return self.param_count()
         d, l = self.d_model, self.num_layers
@@ -175,7 +224,10 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float = 1e-6) -> jax.Array:
 
 
 def activation_fn(name: str) -> Callable[[jax.Array], jax.Array]:
+    """The activation named: "gelu" is the tanh approximation,
+    "gelu_exact" the erf form."""
     return {"silu": jax.nn.silu, "gelu": jax.nn.gelu,
+            "gelu_exact": functools.partial(jax.nn.gelu, approximate=False),
             "relu": jax.nn.relu}[name]
 
 

@@ -9,8 +9,8 @@ Training/prefill uses the chunked block decomposition from the Mamba2 paper
 (intra-chunk quadratic attention-like term + inter-chunk state recurrence,
 `lax.scan` over chunks), giving O(S·Q) work and exact equality with the
 naive recurrence (tested).  Decode keeps (conv_state, ssm_state) per layer —
-constant memory in sequence length, which is why mamba2/zamba2 are the
-archs that run the long_500k cell.
+constant memory in sequence length, which is why mamba2 runs the
+long_500k cell.
 """
 from __future__ import annotations
 

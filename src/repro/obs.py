@@ -19,6 +19,9 @@ counter.
 * The SSD path counter counts, when a program is traced, each SSD scan by
   the path it took (`count_ssd`: the Pallas kernels or XLA);
   `ssd_paths` reads it.
+* The shared-block counter counts, when a program is traced, each
+  application of the hybrid family's shared transformer block, by site
+  (`count_shared`); `shared_sites` reads it.
 
 README.md ("Tracing a training run") says what each name covers.
 """
@@ -36,6 +39,8 @@ GRAD_REDUCE = "train.grad_reduce"
 ADAMW = "train.adamw"
 SSD = "ssm.ssd"
 SSD_KERNEL = "ssm.ssd.kernel"
+SHARED = "hybrid.shared"
+SHARED_ATTN = "hybrid.shared.attn"
 COMMS_GATHER = "comms.gather"
 COMMS_PERMUTE = "comms.permute"
 COMMS_SCATTER = "comms.scatter"
@@ -104,6 +109,19 @@ def ssd_paths() -> Dict[str, int]:
         return dict(_SSD_PATHS)
 
 
+def count_shared(site: int) -> None:
+    """Count one application of the shared block traced at `site`."""
+    with _LOCK:
+        _SHARED_SITES[site] = _SHARED_SITES.get(site, 0) + 1
+
+
+def shared_sites() -> Dict[int, int]:
+    """Shared-block applications traced since this module was first
+    imported, by site."""
+    with _LOCK:
+        return dict(_SHARED_SITES)
+
+
 def compile_seconds(totals: Dict[str, Totals]) -> float:
     """Wall seconds spent compiling: tracing, lowering and the backend
     compile (which holds any persistent-cache read)."""
@@ -118,4 +136,5 @@ if "_EVENTS" not in globals():
     _TOTALS = {p: Totals(0, 0.0) for p in COMPILE_EVENTS.values()}
     _EVENTS: list = []       # (time.time_ns() at its end, phase, seconds)
     _SSD_PATHS: Dict[str, int] = {}
+    _SHARED_SITES: Dict[int, int] = {}
     jax.monitoring.register_event_duration_secs_listener(_record)

@@ -321,3 +321,38 @@ def test_src_names_scopes_spans_and_events_only_through_obs():
         if path.name != "obs.py":
             assert "jax.monitoring" not in text, path
     assert found >= 15
+
+
+@pytest.fixture(scope="module")
+def hybrid_step():
+    """The optimized HLO of a tiny Zamba2 step (five layers, shared-block
+    sites before layers 2 and 4): bf16 compute and remat; and the sites
+    its trace counted."""
+    cfg = reduced_config("zamba2-1.2b")
+    model = build_model(cfg, remat=True)
+    params, opt = init_train_state(model, jax.random.PRNGKey(0))
+    step = make_train_step(model, TrainConfig(compute_dtype=jnp.bfloat16))
+    batch = {"tokens": jnp.zeros((2, 2 * cfg.ssm_chunk), jnp.int32)}
+    before = obs.shared_sites()
+    hlo = jax.jit(step).lower(params, opt, batch).compile().as_text()
+    after = obs.shared_sites()
+    return hlo, {k: v - before.get(k, 0) for k, v in after.items()}
+
+
+@pytest.mark.parametrize("phase", ["forward", "remat", "backward"])
+def test_shared_block_scopes_in_each_pass(hybrid_step, phase):
+    hlo, _ = hybrid_step
+    names = phases(op_names(hlo))[phase]
+    assert any(obs.SHARED in n for n in names)
+    core = [n for n in names if obs.SHARED_ATTN in n]
+    # the attention core nests in the block, so the block's reader counts
+    # it; the block's projections and MLP lie outside the core
+    assert core and all(f"{obs.SHARED}/{obs.SHARED_ATTN}" in n for n in core)
+    assert any(obs.SHARED in n and obs.SHARED_ATTN not in n for n in names)
+    assert not any(obs.SHARED in n for n in phases(op_names(hlo))[
+        "optimizer"])
+
+
+def test_shared_counter_counts_each_site_once_a_trace(hybrid_step):
+    _, traced = hybrid_step
+    assert traced == {0: 1, 1: 1}
