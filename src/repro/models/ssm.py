@@ -78,7 +78,7 @@ def ssd_kernel_fits(x: jax.Array, b: jax.Array, chunk: int) -> bool:
     if not (mesh.empty or mesh.size == 1 or mesh.are_all_axes_manual):
         return False
     _, _, h, p = x.shape
-    return ssd_scan.kernel_fits(h, p, b.shape[-1], chunk)
+    return ssd_scan.kernel_fits(h, p, b.shape[-1], chunk, x.dtype)
 
 
 def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,

@@ -18,7 +18,9 @@ counter.
   profiler trace's start time).
 * The SSD path counter counts, when a program is traced, each SSD scan by
   the path it took (`count_ssd`: the Pallas kernels or XLA);
-  `ssd_paths` reads it.
+  `ssd_paths` reads it.  Beside it, each scan traced on the kernels is
+  counted by the lanes of its head block (`count_ssd_lanes`), which
+  `ssd_head_block_lanes` reads.
 * The shared-block counter counts, when a program is traced, each
   application of the hybrid family's shared transformer block, by site
   (`count_shared`); `shared_sites` reads it.
@@ -109,6 +111,20 @@ def ssd_paths() -> Dict[str, int]:
         return dict(_SSD_PATHS)
 
 
+def count_ssd_lanes(lanes: int) -> None:
+    """Count one SSD scan traced on the kernels at a head block of
+    `lanes` lanes."""
+    with _LOCK:
+        _SSD_LANES[lanes] = _SSD_LANES.get(lanes, 0) + 1
+
+
+def ssd_head_block_lanes() -> Dict[int, int]:
+    """SSD scans traced on the kernels since this module was first
+    imported, by the lanes of their head block."""
+    with _LOCK:
+        return dict(_SSD_LANES)
+
+
 def count_shared(site: int) -> None:
     """Count one application of the shared block traced at `site`."""
     with _LOCK:
@@ -136,5 +152,6 @@ if "_EVENTS" not in globals():
     _TOTALS = {p: Totals(0, 0.0) for p in COMPILE_EVENTS.values()}
     _EVENTS: list = []       # (time.time_ns() at its end, phase, seconds)
     _SSD_PATHS: Dict[str, int] = {}
+    _SSD_LANES: Dict[int, int] = {}
     _SHARED_SITES: Dict[int, int] = {}
     jax.monitoring.register_event_duration_secs_listener(_record)

@@ -186,6 +186,7 @@ class TrainSupervisor:
                 try:
                     compiled = obs.compile_totals()
                     ssd = obs.ssd_paths()
+                    lanes = obs.ssd_head_block_lanes()
                     t0 = time.perf_counter()
                     with StepTraceAnnotation(obs.STEP, step_num=step):
                         with TraceAnnotation(obs.DISPATCH):
@@ -204,10 +205,15 @@ class TrainSupervisor:
                     traced = {k: v - ssd.get(k, 0)
                               for k, v in obs.ssd_paths().items()
                               if v != ssd.get(k, 0)}
+                    widths = {k: v - lanes.get(k, 0) for k, v in
+                              obs.ssd_head_block_lanes().items()
+                              if v != lanes.get(k, 0)}
                     if traced:
                         log(f"[obs] step {step} traced the SSD scan: "
                             + ", ".join(f"{k} {v}" for k, v in
-                                        sorted(traced.items())))
+                                        sorted(traced.items()))
+                            + "".join(f"; head block {k} lanes {v}"
+                                      for k, v in sorted(widths.items())))
                     if self.monitor.observe(step, dt):
                         log(f"[ft] straggler at step {step}: {dt:.3f}s "
                             f"(ewma {self.monitor.ewma:.3f}s)")

@@ -80,12 +80,12 @@ def test_flash_attention_compiles_head_dim_128_seq_2048(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _ssd_shapes(one_chip, bs, s, h, p, n):
-    return (_shape((bs, s, h * p), jnp.bfloat16, one_chip),
+def _ssd_shapes(one_chip, bs, s, h, p, n, dtype=jnp.bfloat16):
+    return (_shape((bs, s, h * p), dtype, one_chip),
             _shape((bs, s, h), jnp.float32, one_chip),
             _shape((h,), jnp.float32, one_chip),
-            _shape((bs, s, n), jnp.bfloat16, one_chip),
-            _shape((bs, s, n), jnp.bfloat16, one_chip))
+            _shape((bs, s, n), dtype, one_chip),
+            _shape((bs, s, n), dtype, one_chip))
 
 
 def test_ssd_chunk_intra_compiles_at_mamba2_widths(one_chip):
@@ -98,16 +98,29 @@ def test_ssd_chunk_intra_compiles_at_mamba2_widths(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("widths", [
-    (48, 64, 128, 512),     # mamba2-780m: heads, head_dim, state, chunk
-    (64, 64, 64, 256),      # zamba2-1.2b's Mamba2 layers
+@pytest.mark.parametrize("widths,lanes,dtype", [
+    # heads, head_dim, state, chunk; the head block's lanes; the inputs
+    ((48, 64, 128, 512), 512, jnp.bfloat16),      # mamba2-780m
+    ((64, 64, 64, 256), 1024, jnp.bfloat16),      # zamba2-1.2b's Mamba2
+    # the edges of what `kernel_fits` admits: the longest chunk and widest
+    # state, f32 there, f32 at mamba2-780m's widths, heads of 128 lanes
+    ((48, 64, 256, 1024), 128, jnp.bfloat16),
+    ((48, 64, 256, 1024), 128, jnp.float32),
+    ((48, 64, 128, 512), 128, jnp.float32),
+    ((24, 128, 128, 512), 512, jnp.bfloat16),
 ])
-def test_ssd_kernels_compile_forward_and_backward(one_chip, widths):
-    """Both SSD kernels, as one layer's gradient runs them at S 2048: the
-    forward and the backward fit v5e's VMEM and tiling."""
+def test_ssd_kernels_compile_forward_and_backward(one_chip, widths, lanes,
+                                                  dtype):
+    """Both SSD kernels, as one layer's gradient runs them at S 2048, at
+    the head block `heads_per_block` picks (its groups a rolled loop, its
+    reckoned VMEM the kernels' limit): the forward and the backward fit
+    v5e's VMEM and tiling at that width, for each model and at the edges
+    of what `kernel_fits` admits."""
+    from repro import obs
     from repro.kernels.ssd_scan import kernel_fits, ssd_chunk_intra
     h, p, n, q = widths
-    assert kernel_fits(h, p, n, q)
+    assert kernel_fits(h, p, n, q, dtype)
+    traced = obs.ssd_head_block_lanes().get(lanes, 0)
 
     def loss(x, dt, a, b, c):
         y, states, cum = ssd_chunk_intra(x, dt, a, b, c, chunk=q)
@@ -116,8 +129,9 @@ def test_ssd_kernels_compile_forward_and_backward(one_chip, widths):
 
     step = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))
     compiled = jax.jit(step).lower(
-        *_ssd_shapes(one_chip, 1, 2048, h, p, n)).compile()
+        *_ssd_shapes(one_chip, 1, 2048, h, p, n, dtype)).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 2
+    assert obs.ssd_head_block_lanes().get(lanes, 0) == traced + 1
 
 
 # ---------------------------------------------------------------------- #

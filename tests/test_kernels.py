@@ -167,6 +167,15 @@ def _rel(u, v):
 SSD_NAMES = ("y", "final", "dx", "ddt", "dA", "dB", "dC")
 
 
+def _budget_for_lanes(monkeypatch, ssd_scan, lanes, p, n, q, dtype):
+    """VMEM_BUDGET set to what a head block of `lanes` lanes takes, so
+    that `heads_per_block` picks it (or narrower, where it does not divide
+    the heads)."""
+    hb = max(1, lanes // p)
+    monkeypatch.setattr(ssd_scan, "VMEM_BUDGET", max(
+        ssd_scan.vmem_bytes(hb, p, n, q, dtype, bwd) for bwd in (False, True)))
+
+
 @pytest.mark.parametrize("case", [
     # bs, s, h, p, n, chunk, tile, lanes: 4 chunks of 4 tiles, 4 heads in
     # one 64-lane block, N 64, an entering state
@@ -176,13 +185,22 @@ SSD_NAMES = ("y", "final", "dx", "ddt", "dA", "dB", "dC")
     (1, 256, 4, 64, 128, 128, 32, 128),
     # one block of two 128-lane groups of two heads
     (1, 128, 4, 64, 64, 64, 16, 256),
+    # one block of four 128-lane groups (the rolled loop's four turns),
+    # N 128, 2 chunks of 4 tiles
+    (1, 256, 8, 64, 128, 128, 32, 512),
+    # one block of three groups: 6 heads, their rows padded to 8
+    (1, 256, 6, 64, 128, 128, 32, 384),
+    # heads of 128 lanes: one head a group, two groups a block
+    (1, 256, 2, 128, 64, 128, 32, 256),
 ])
 def test_ssd_kernel_path_matches_xla_and_reference(case, monkeypatch):
     from repro.kernels import ssd_scan
     from repro.models.ssm import ssd_chunked, ssd_reference
     bs, s, h, p, n, q, tile, lanes = case
     monkeypatch.setattr(ssd_scan, "TILE", tile)
-    monkeypatch.setattr(ssd_scan, "LANES", lanes)
+    _budget_for_lanes(monkeypatch, ssd_scan, lanes, p, n, q, jnp.float32)
+    assert ssd_scan.heads_per_block(h, p, n, q, jnp.float32) == min(
+        h, max(1, lanes // p))
     args, h0, wy = _ssd_inputs(bs, s, h, p, n)
 
     def path(impl):
@@ -199,17 +217,28 @@ def test_ssd_kernel_path_matches_xla_and_reference(case, monkeypatch):
         assert _rel(g, r) < 2e-4, name
 
 
-def test_ssd_kernel_path_bf16_as_close_as_xla(monkeypatch):
+@pytest.mark.parametrize("case", [
+    # s, h, p, n, chunk, tile, lanes (None: the module's budget)
+    (128, 4, 32, 64, 64, 16, None),
+    # one block of 512 lanes: four turns of the rolled group loop
+    (128, 8, 64, 64, 64, 16, 512),
+])
+def test_ssd_kernel_path_bf16_as_close_as_xla(case, monkeypatch):
     """bf16 compute: the kernels' error against the f32 oracle stays near
     the XLA path's (same precision policy: bf16 MXU operands, f32 decay)."""
     from repro.kernels import ssd_scan
     from repro.models.ssm import ssd_chunked, ssd_reference
-    monkeypatch.setattr(ssd_scan, "TILE", 16)
-    args, h0, wy = _ssd_inputs(1, 128, 4, 32, 64, seed=5)
+    s, h, p, n, q, tile, lanes = case
+    monkeypatch.setattr(ssd_scan, "TILE", tile)
+    if lanes:
+        _budget_for_lanes(monkeypatch, ssd_scan, lanes, p, n, q,
+                          jnp.bfloat16)
+        assert ssd_scan.heads_per_block(h, p, n, q) * p == lanes
+    args, h0, wy = _ssd_inputs(1, s, h, p, n, seed=5)
 
     def path(impl):
         return lambda x, dt, a, b, c, h0: ssd_chunked(
-            x, dt, a, b, c, 64, h0, impl=impl)
+            x, dt, a, b, c, q, h0, impl=impl)
 
     got = _ssd_outputs_and_grads(path("interpret"), args, h0, wy,
                                  jnp.bfloat16)
@@ -308,3 +337,91 @@ def test_ssd_path_follows_shape(case, monkeypatch):
     x = jax.ShapeDtypeStruct((1, s, h, p), jnp.bfloat16)
     b = jax.ShapeDtypeStruct((1, s, n), jnp.bfloat16)
     assert ssm.ssd_kernel_fits(x, b, q) == (path == "kernel")
+
+
+def _ssd_kernel_calls(h, p, n, q, s=2048):
+    """The pallas_call equations of one SSD layer's forward and backward
+    at these widths (bf16), traced on the CPU and not lowered."""
+    from repro.kernels import ssd_scan
+
+    def loss(*args):
+        y, states, cum = ssd_scan.ssd_chunk_intra(*args, chunk=q)
+        return (jnp.sum(y.astype(jnp.float32)) + jnp.sum(states)
+                + jnp.sum(cum))
+
+    args = (jax.ShapeDtypeStruct((1, s, h * p), jnp.bfloat16),
+            jax.ShapeDtypeStruct((1, s, h), jnp.float32),
+            jax.ShapeDtypeStruct((h,), jnp.float32),
+            jax.ShapeDtypeStruct((1, s, n), jnp.bfloat16),
+            jax.ShapeDtypeStruct((1, s, n), jnp.bfloat16))
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(*args)
+    calls = [e for e in _equations(jaxpr.jaxpr)
+             if e.primitive.name == "pallas_call"]
+    # forward, then backward (the dimension semantics tell them apart)
+    return sorted(calls, key=lambda e: e.params["compiler_params"][
+        "mosaic_tpu"].dimension_semantics[2] == "arbitrary")
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr, those of the jaxprs inside it included."""
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+@pytest.mark.parametrize("widths,lanes", [
+    ((48, 64, 128, 512), 512),      # mamba2-780m: 8 heads a block
+    ((64, 64, 64, 256), 1024),      # zamba2-1.2b's Mamba2 layers: 16
+])
+def test_ssd_head_block_follows_the_vmem_budget(widths, lanes, monkeypatch):
+    """The rule picks the widest head block of whole 128-lane groups whose
+    reckoned VMEM fits the budget, passes that reckoning to each kernel as
+    its VMEM limit (never under Mosaic's scoped default), and falls back
+    to a narrower block over the budget."""
+    from repro.kernels import ssd_scan
+    h, p, n, q = widths
+    hb = ssd_scan.heads_per_block(h, p, n, q)
+    assert hb * p == lanes
+    fwd, bwd = _ssd_kernel_calls(h, p, n, q)
+    for call, backward in ((fwd, False), (bwd, True)):
+        limit = call.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+        need = ssd_scan.vmem_bytes(hb, p, n, q, jnp.bfloat16, backward)
+        assert limit == max(need, ssd_scan.SCOPED_VMEM)
+        assert need <= ssd_scan.VMEM_BUDGET
+    # a budget a byte short of this block's backward takes the next one
+    narrower = max(k for k in range(1, hb) if h % k == 0
+                   and (k * p) % 128 == 0)
+    monkeypatch.setattr(ssd_scan, "VMEM_BUDGET", ssd_scan.vmem_bytes(
+        hb, p, n, q, jnp.bfloat16, backward=True) - 1)
+    assert ssd_scan.heads_per_block(h, p, n, q) == narrower
+    assert ssd_scan.kernel_fits(h, p, n, q)
+    # a budget under the narrowest block's takes that block, its limit
+    # still its reckoning
+    monkeypatch.setattr(ssd_scan, "VMEM_BUDGET", ssd_scan.vmem_bytes(
+        128 // p, p, n, q, jnp.bfloat16, backward=True) - 1)
+    assert ssd_scan.heads_per_block(h, p, n, q) == 128 // p
+    assert ssd_scan.kernel_fits(h, p, n, q)
+
+
+def test_ssd_kernel_bodies_do_not_grow_with_the_head_block(monkeypatch):
+    """The kernels' bodies, forward and backward, at the width the rule
+    picks for mamba2-780m hold as many equations as at 256 lanes, within
+    10%: the head block's groups are a rolled loop, so tracing and
+    lowering cost the same at any width."""
+    from repro.kernels import ssd_scan
+    h, p, n, q = 48, 64, 128, 512
+    wide = [len(list(_equations(c.params["jaxpr"])))
+            for c in _ssd_kernel_calls(h, p, n, q)]
+    assert ssd_scan.heads_per_block(h, p, n, q) * p > 256
+    monkeypatch.setattr(ssd_scan, "VMEM_BUDGET", max(
+        ssd_scan.vmem_bytes(256 // p, p, n, q, jnp.bfloat16, bwd)
+        for bwd in (False, True)))
+    assert ssd_scan.heads_per_block(h, p, n, q) * p == 256
+    narrow = [len(list(_equations(c.params["jaxpr"])))
+              for c in _ssd_kernel_calls(h, p, n, q)]
+    for w, k in zip(wide, narrow):
+        assert abs(w - k) <= 0.1 * k, (wide, narrow)

@@ -233,6 +233,33 @@ def test_supervisor_logs_the_ssd_paths_a_compile_traced(tmp_path):
                             r"xla \d+", s), s
 
 
+def test_supervisor_names_the_ssd_head_block_a_compile_traced(tmp_path):
+    """A step that traces the SSD on the kernels logs the lanes of the head
+    block they were traced with, beside the path (here the kernels under
+    the interpreter, traced and not run: 4 heads of 64, one 256-lane
+    block)."""
+    from repro.models import ssm
+    bs, s, h, p, n, q = 1, 128, 4, 64, 16, 64
+    args = (jnp.zeros((bs, s, h, p)), jnp.ones((bs, s, h)), -jnp.ones((h,)),
+            jnp.zeros((bs, s, n)), jnp.zeros((bs, s, n)))
+    lanes = obs.ssd_head_block_lanes()
+
+    def step_fn(step, state):
+        if step == 0:
+            jax.make_jaxpr(lambda *a: ssm.ssd_chunked(
+                *a, q, impl="interpret"))(*args)
+        return state, {}
+
+    logs = []
+    sup = TrainSupervisor(ckpt_dir=str(tmp_path), ckpt_every=100)
+    sup.run(state=(jnp.zeros(2),), num_steps=2, step_fn=step_fn,
+            log=logs.append, log_every=0)
+    traced = [m for m in logs if "traced the SSD" in m]
+    assert traced == ["[obs] step 0 traced the SSD scan: kernel 1; "
+                      "head block 256 lanes 1"], logs
+    assert obs.ssd_head_block_lanes().get(256, 0) == lanes.get(256, 0) + 1
+
+
 def test_compile_counter_counts_a_first_compile():
     f = jax.jit(lambda x: jnp.sin(x) * 3.0 + 1.0)
     before = obs.compile_totals()
