@@ -5,4 +5,4 @@ from .collectives import (tree_all_gather, tree_all_reduce,  # noqa: F401
                           tree_all_to_all, tree_broadcast, tree_reduce,
                           tree_reduce_scatter)
 from .mesh_axes import CollectiveContext, AxisSchedules  # noqa: F401
-from .overlap import BucketedAllReduce, compressed_all_reduce  # noqa: F401
+from .overlap import BucketedAllReduce  # noqa: F401

@@ -1,6 +1,5 @@
-"""Beyond-paper distributed-optimization layers on top of the tree
-collectives: bucketed gradient reduction (overlap hooks) and wire
-compression.
+"""Beyond-paper distributed-optimization layer on top of the tree
+collectives: bucketed gradient reduction (overlap hooks).
 
 * `BucketedAllReduce` — partitions the gradient pytree into ~equal-byte
   buckets; each bucket is reduced independently, so on hardware the bucket
@@ -8,9 +7,8 @@ compression.
   the backward, overlaps backprop compute — the classic DDP trick).  The
   bucket schedule also keeps each tree-pipeline transfer long enough to
   amortise the (P+depth)/P pipeline fill of the paper's schedules.
-* `compressed_all_reduce` — casts the wire payload (bf16 by default) while
-  accumulating in f32 via the tree reduce-scatter's accumulator; the paper
-  optimises bytes-on-the-wire, compression multiplies that directly.
+  `wire_dtype` (bf16 by default) casts the wire payload while the tree
+  reduce-scatter accumulates in f32.
 """
 from __future__ import annotations
 
@@ -87,12 +85,3 @@ class BucketedAllReduce:
                         leaves[i].shape).astype(leaves[i].dtype)
                     off += n
         return jax.tree_util.tree_unflatten(treedef, out)
-
-
-def compressed_all_reduce(x: jax.Array, rs_prog: PermuteProgram,
-                          ag_prog: PermuteProgram, axis_name: str,
-                          wire_dtype=jnp.bfloat16) -> jax.Array:
-    """All-reduce with bf16 (or fp8) wire payload and f32 accumulation."""
-    return tree_all_reduce(x.astype(wire_dtype), rs_prog, ag_prog,
-                           axis_name,
-                           accum_dtype=jnp.float32).astype(x.dtype)

@@ -1,6 +1,3 @@
 # Pallas TPU kernels for the perf-critical compute layers, each with a
 # pure-jnp oracle in ref.py and interpret=True validation in tests.
-from .ops import (chunk_accum, flash_attention,  # noqa: F401
-                  flash_attention_bshd, enable_flash_in_models,
-                  disable_flash_in_models)
 from .ssd_scan import ssd_chunk_intra  # noqa: F401

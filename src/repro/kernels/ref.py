@@ -1,45 +1,8 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose references)."""
+"""Pure-jnp oracle for the SSD Pallas kernel (the allclose reference)."""
 from __future__ import annotations
-
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-NEG_INF = -2.0 ** 30
-
-
-def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                  causal: bool = True, window: Optional[int] = None,
-                  prefix_len: int = 0,
-                  logit_cap: Optional[float] = None) -> jax.Array:
-    """q: [B,H,Sq,D]; k,v: [B,Hkv,Skv,D] -> [B,H,Sq,D]."""
-    b, h, sq, d = q.shape
-    hkv = k.shape[1]
-    g = h // hkv
-    qg = q.reshape(b, hkv, g, sq, d).astype(jnp.float32)
-    logits = jnp.einsum("bhgsd,bhtd->bhgst", qg, k.astype(jnp.float32))
-    logits = logits / (d ** 0.5)
-    if logit_cap is not None:
-        logits = logit_cap * jnp.tanh(logits / logit_cap)
-    skv = k.shape[2]
-    q_pos = jnp.arange(sq)[:, None]
-    kv_pos = jnp.arange(skv)[None, :]
-    if causal:
-        ok = kv_pos <= q_pos
-        if window is not None:
-            ok &= kv_pos > q_pos - window
-        if prefix_len:
-            ok |= kv_pos < prefix_len
-        logits = jnp.where(ok[None, None, None], logits, NEG_INF)
-    p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhgst,bhtd->bhgsd", p, v.astype(jnp.float32))
-    return out.reshape(b, h, sq, d).astype(q.dtype)
-
-
-def chunk_accum_reference(acc: jax.Array, update: jax.Array) -> jax.Array:
-    """acc: [N, C] f32; update: [N, C] any dtype -> acc + update (f32)."""
-    return acc + update.astype(acc.dtype)
 
 
 def ssd_chunk_reference(x: jax.Array, dt: jax.Array, a: jax.Array,

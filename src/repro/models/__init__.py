@@ -1,3 +1,3 @@
 from .common import ModelConfig, Params, cross_entropy_loss  # noqa: F401
 from .model_zoo import Model, build_model  # noqa: F401
-from .attention import MaskSpec, attend, set_flash_impl  # noqa: F401
+from .attention import MaskSpec, attend  # noqa: F401

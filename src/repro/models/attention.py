@@ -3,7 +3,7 @@ prefix-LM, cross-attention, and KV-cache decode.
 
 Masks are never materialised as [S, T] arrays — they are *described* by
 (causal, window, prefix_len) plus position vectors and evaluated inline.
-Three execution paths:
+Two execution paths:
 
 * direct      — small sequences / decode: one einsum, inline mask.
 * blockwise   — long sequences: lax.map over query blocks, online-softmax
@@ -12,8 +12,6 @@ Three execution paths:
                 window-adjacent KV blocks are visited, so compute is
                 O(S * window) — this is what makes mixtral's long_500k
                 cell tractable.
-* kernel      — the Pallas flash kernel (repro.kernels) on TPU; registered
-                via `set_flash_impl`, validated against the paths above.
 """
 from __future__ import annotations
 
@@ -27,16 +25,9 @@ import jax.numpy as jnp
 from .common import (ModelConfig, NEG_INF, Params, apply_rope, dense_init,
                      rms_norm, softcap)
 
-_FLASH_IMPL = None
 BLOCKWISE_THRESHOLD = 2048      # use blockwise path above this many kv rows
 BLOCK_Q = 1024
 BLOCK_KV = 1024
-
-
-def set_flash_impl(fn) -> None:
-    """Register the Pallas kernel as the long-sequence implementation."""
-    global _FLASH_IMPL
-    _FLASH_IMPL = fn
 
 
 @dataclasses.dataclass(frozen=True)
@@ -235,10 +226,6 @@ def attend(q: jax.Array, k: jax.Array, v: jax.Array,
     scaled by `scale`, D^-1/2 unless given."""
     t = k.shape[1]
     s = q.shape[1]
-    if _FLASH_IMPL is not None and s > 1:
-        if scale is not None:      # the kernel scales by D^-1/2
-            q = q * jnp.asarray(scale * q.shape[-1] ** 0.5, q.dtype)
-        return _FLASH_IMPL(q, k, v, q_pos, kv_pos, spec, logit_cap)
     if s == 1 or max(s, t) <= BLOCKWISE_THRESHOLD:
         return _direct_attend(q, k, v, q_pos, kv_pos, spec, logit_cap,
                               scale)

@@ -60,26 +60,6 @@ def _shape(shape, dtype, sharding):
 # Pallas kernels on one described chip (interpret=False)
 # ---------------------------------------------------------------------- #
 
-def test_chunk_accum_compiles(one_chip):
-    from repro.kernels.chunk_accum import chunk_accum
-    acc = _shape((256, 4096), jnp.float32, one_chip)
-    upd = _shape((256, 4096), jnp.bfloat16, one_chip)
-    compiled = jax.jit(
-        lambda a, u: chunk_accum(a, u, interpret=False)).lower(
-            acc, upd).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_flash_attention_compiles_head_dim_128_seq_2048(one_chip):
-    from repro.kernels.flash_attention import flash_attention
-    q = _shape((1, 8, 2048, 128), jnp.bfloat16, one_chip)
-    kv = _shape((1, 2, 2048, 128), jnp.bfloat16, one_chip)
-    compiled = jax.jit(
-        lambda q, k, v: flash_attention(q, k, v, interpret=False)).lower(
-            q, kv, kv).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 def _ssd_shapes(one_chip, bs, s, h, p, n, dtype=jnp.bfloat16):
     return (_shape((bs, s, h * p), dtype, one_chip),
             _shape((bs, s, h), jnp.float32, one_chip),
@@ -177,3 +157,18 @@ def test_tree_all_to_all_compiles_on_4_chips(mesh4, ctx4):
         lambda v: tree_all_to_all(v, prog, "data"),
         mesh4, (16, 1 << 16))
     assert "collective-permute" in hlo and "all-to-all" not in hlo
+
+
+@pytest.mark.parametrize("kind", ["reduce_scatter", "broadcast", "reduce"])
+def test_tree_kind_compiles_on_4_chips(mesh4, ctx4, kind):
+    """The other kinds, through the same staging path: each compiles to
+    `ppermute`s and no XLA collective."""
+    from repro.comms import collectives
+    root = {} if kind == "reduce_scatter" else {"root": 1}
+    prog = ctx4.collectives.program(ctx4.topology("data"), kind=kind, **root)
+    tree = getattr(collectives, "tree_" + kind)
+    hlo = _compile_on_mesh(lambda v: tree(v[0], prog, "data")[None],
+                           mesh4, (4, 1 << 18))
+    assert "collective-permute" in hlo
+    assert not any(op in hlo for op in ("all-reduce", "reduce-scatter",
+                                        "all-gather"))

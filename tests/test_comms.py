@@ -2,25 +2,7 @@
 
 Each test spawns a subprocess with XLA_FLAGS=--xla_force_host_platform_
 device_count=8 (the main pytest process must keep seeing ONE device)."""
-import os
-import subprocess
-import sys
-import textwrap
-
-import pytest
-
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
-
-
-def run_snippet(code: str) -> str:
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               PYTHONPATH=SRC)
-    out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                         capture_output=True, text=True, env=env,
-                         timeout=600)
-    assert out.returncode == 0, f"stderr:\n{out.stderr}\nstdout:\n{out.stdout}"
-    return out.stdout
+from conftest import run_snippet
 
 
 def test_tree_collectives_match_references():

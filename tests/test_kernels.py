@@ -1,94 +1,11 @@
 """Pallas kernel validation in interpret mode: shape/dtype sweeps against
-the pure-jnp oracles in repro.kernels.ref."""
+the pure-jnp oracle in repro.kernels.ref and the XLA path."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.chunk_accum import chunk_accum
-from repro.kernels.flash_attention import flash_attention
-from repro.kernels.ref import chunk_accum_reference, mha_reference
-
 KEY = jax.random.PRNGKey(7)
-
-
-def qkv(b, h, hkv, s, d, dtype):
-    ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (b, h, s, d)).astype(dtype)
-    k = jax.random.normal(ks[1], (b, hkv, s, d)).astype(dtype)
-    v = jax.random.normal(ks[2], (b, hkv, s, d)).astype(dtype)
-    return q, k, v
-
-
-@pytest.mark.parametrize("shape", [
-    (1, 2, 2, 128, 16),    # MHA
-    (2, 4, 2, 256, 32),    # GQA
-    (1, 4, 1, 128, 64),    # MQA
-    (2, 2, 2, 512, 16),    # longer seq
-])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_shapes_dtypes(shape, dtype):
-    b, h, hkv, s, d = shape
-    q, k, v = qkv(b, h, hkv, s, d, dtype)
-    got = flash_attention(q, k, v, block_q=64, block_kv=64, interpret=True)
-    ref = mha_reference(q, k, v)
-    atol = 1e-5 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(ref, np.float32), atol=atol)
-
-
-@pytest.mark.parametrize("kwargs", [
-    dict(causal=False),
-    dict(causal=True, window=64),
-    dict(causal=True, prefix_len=32),
-    dict(causal=True, logit_cap=50.0),
-    dict(causal=True, window=96, logit_cap=30.0),
-])
-def test_flash_attention_mask_variants(kwargs):
-    q, k, v = qkv(2, 4, 2, 256, 32, jnp.float32)
-    got = flash_attention(q, k, v, block_q=64, block_kv=64, interpret=True,
-                          **kwargs)
-    ref = mha_reference(q, k, v, **kwargs)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-5)
-
-
-def test_flash_attention_block_invariance():
-    q, k, v = qkv(1, 2, 2, 256, 32, jnp.float32)
-    a = flash_attention(q, k, v, block_q=32, block_kv=64, interpret=True)
-    b = flash_attention(q, k, v, block_q=128, block_kv=128, interpret=True)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
-
-
-@pytest.mark.parametrize("shape", [(8, 512), (16, 1024), (32, 2048)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.float16])
-def test_chunk_accum_sweep(shape, dtype):
-    n, c = shape
-    acc = jax.random.normal(KEY, (n, c), jnp.float32)
-    upd = jax.random.normal(jax.random.PRNGKey(3), (n, c)).astype(dtype)
-    got = chunk_accum(acc, upd, interpret=True)
-    ref = chunk_accum_reference(acc, upd)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-6)
-
-
-def test_flash_hook_in_models():
-    """The kernel can be registered as the models' attention impl and
-    produces the same result as the jnp path."""
-    from repro.kernels.ops import enable_flash_in_models, \
-        disable_flash_in_models
-    from repro.models.attention import attend, MaskSpec
-    b, s, h, hkv, d = 1, 128, 4, 2, 32
-    ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (b, s, h, d))
-    k = jax.random.normal(ks[1], (b, s, hkv, d))
-    v = jax.random.normal(ks[2], (b, s, hkv, d))
-    pos = jnp.arange(s)
-    base = attend(q, k, v, pos, pos, MaskSpec(causal=True))
-    enable_flash_in_models()
-    try:
-        got = attend(q, k, v, pos, pos, MaskSpec(causal=True))
-    finally:
-        disable_flash_in_models()
-    np.testing.assert_allclose(np.asarray(got), np.asarray(base), atol=1e-5)
 
 
 # ---------------------------------------------------------------------- #
